@@ -70,7 +70,8 @@ from repro.serve.workload import required_max_seq, staggered_requests
 # device or memory kind is a real transfer.
 TRANSFER_PRIMITIVES = frozenset({
     "infeed", "outfeed",
-    "pure_callback", "io_callback", "debug_callback", "callback",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
+    "callback",
 })
 
 # Float dtypes a tick may produce.  Everything else (notably float64 /
@@ -112,8 +113,10 @@ def read_path_for(cfg) -> str:
 
 def build_engine(arch: str, *, num_slots: int = 2, chunk: int = 4,
                  block_size: int = 4, kv_dtype: str = "fp"):
-    """Smoke-scale engine + its workload for one registry arch."""
-    cfg = reduce_config(get_config(arch))
+    """Smoke-scale engine + its workload for one registry arch.  The audit
+    runs on the CPU, so a forced 'pallas' read runs interpreted."""
+    cfg = dataclasses.replace(reduce_config(get_config(arch)),
+                              pallas_interpret=True)
     model = make_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     reqs = staggered_requests(cfg, n_requests=4, base_len=12,

@@ -95,7 +95,7 @@ def _f64_bad():
     def f(x):
         return jnp.cumsum(x) * 2.0
     aval = jax.ShapeDtypeStruct((8,), jnp.float64)
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         return _audit(_entry("f64_tick", f, (aval,)))
 
 

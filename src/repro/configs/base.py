@@ -85,7 +85,11 @@ class ModelConfig:
     # execution knobs
     scan_layers: bool = True
     remat: str = "full"            # none | full | dots
-    use_pallas: bool = False       # single-chip TPU hot path (interpret-tested)
+    use_pallas: bool = False       # single-chip TPU hot path
+    # run the Pallas kernels in the interpreter: how CPU tests exercise
+    # them.  Never inferred from the platform — off a TPU, a kernel that
+    # was not asked to interpret fails to lower.
+    pallas_interpret: bool = False
     # Adam m/v dtype (perf A7): 'bfloat16' halves optimizer-state HBM for
     # the 141B-param mixtral, the tightest (model x 256-chip) combination.
     opt_state_dtype: str = "float32"

@@ -75,7 +75,7 @@ def shift_subtract_div(numer: jax.Array, denom: jax.Array, out_bits: int) -> jax
     fractional bits.  Inputs must satisfy numer << out_bits < 2**62 — callers
     keep numer in <= 30 bits.
     """
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         numer = jnp.asarray(numer).astype(jnp.int64)
         denom = jnp.asarray(denom).astype(jnp.int64)
         numer, denom = jnp.broadcast_arrays(numer, denom)

@@ -199,7 +199,7 @@ def gn_layernorm_hwsim(
     q = fxp.LN_IN_Q
     xi32 = q.quantize(x.astype(jnp.float32))                     # Q8.8 int32
     c = x.shape[-1]
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         xi = xi32.astype(jnp.int64)
         ex = jnp.sum(xi, axis=-1, keepdims=True) // c            # Q8.8 mean
         ex2 = jnp.sum(xi * xi, axis=-1, keepdims=True) // c      # Q.16

@@ -146,7 +146,7 @@ def gn_softmax_hwsim(
     shift = 3 + cfg.frac_bits
     frac = d_int >> shift
     rem = d_int & (cfg.residual_entries - 1)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         coarse = jnp.asarray(coarse_i).astype(jnp.int64)
         residual = jnp.asarray(residual_i).astype(jnp.int64)
         a = coarse[frac]                           # Q1.vb int

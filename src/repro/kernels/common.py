@@ -1,12 +1,9 @@
 """Shared in-kernel helpers for the GN Pallas kernels.
 
-The LUT units of the paper, expressed MXU-idiomatically: a ROM lookup is a
-one-hot × table matmul; the factorized exponential is two such lookups plus a
+The LUT units of the paper: a ROM lookup is a select over the table's
+entries, which are fixed by the LUT config and so enter the kernel as scalar
+constants; the factorized exponential is two such lookups plus a
 fixed-point-rounded product (Eq. 4).
-
-LUTs are passed into kernels as (1, 128) lane-padded VMEM operands (Pallas
-forbids captured array constants).  One-hot columns beyond the true entry
-count are never set, so the zero padding is inert.
 """
 from __future__ import annotations
 
@@ -15,51 +12,23 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import luts as lut_lib
-from repro.core.luts import RsqrtConfig, SoftmaxLUTConfig
-
-LANE = 128
+from repro.core.luts import SoftmaxLUTConfig
 
 
-def pad_lut(values: np.ndarray) -> jnp.ndarray:
-    """1-D LUT -> (1, 128k) lane-aligned operand."""
-    n = values.shape[0]
-    n_p = (n + LANE - 1) // LANE * LANE
-    out = np.zeros((1, n_p), np.float32)
-    out[0, :n] = values
-    return jnp.asarray(out)
+def lut_lookup(idx: jax.Array, values: np.ndarray) -> jax.Array:
+    """ROM lookup ``values[idx]`` as a chain of selects over the entries.
+
+    The entries are trace-time constants, so the result is bit-equal to the
+    table; indices outside it read 0.  Selects lower on any tile shape (a
+    one-hot matmul needs an (r*c, 1) reshape that Mosaic refuses).
+    """
+    out = jnp.zeros(idx.shape, jnp.float32)
+    for j, v in enumerate(values):
+        out = jnp.where(idx == j, jnp.float32(v), out)
+    return out
 
 
-def exp_lut_operands(cfg: SoftmaxLUTConfig) -> tuple[jnp.ndarray, jnp.ndarray]:
-    coarse, residual = lut_lib.exp_luts(cfg)
-    return pad_lut(coarse), pad_lut(residual)
-
-
-def rsqrt_lut_operand(cfg: RsqrtConfig) -> jnp.ndarray:
-    return pad_lut(lut_lib.rsqrt_mantissa_lut(cfg))
-
-
-def lut_lookup(idx: jax.Array, lut2d: jax.Array) -> jax.Array:
-    """ROM lookup as one-hot matmul.  idx int32 (r, c), lut2d (1, np)."""
-    r, c = idx.shape
-    n_p = lut2d.shape[-1]
-    flat = idx.reshape(r * c, 1)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (r * c, n_p), 1)
-    onehot = (flat == iota).astype(jnp.float32)
-    vals = jax.lax.dot_general(
-        onehot,
-        lut2d.reshape(n_p, 1),
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    return vals.reshape(r, c)
-
-
-def factorized_exp(
-    delta: jax.Array,
-    coarse2d: jax.Array,
-    residual2d: jax.Array,
-    cfg: SoftmaxLUTConfig,
-) -> jax.Array:
+def factorized_exp(delta: jax.Array, cfg: SoftmaxLUTConfig) -> jax.Array:
     """e^{-Δ} on the fixed-point grid via the coarse/residual LUT pair.
 
     Δ >= 0 float32 (any 2D block shape).  Entries beyond the coarse LUT's
@@ -71,7 +40,8 @@ def factorized_exp(
     d_int = jnp.clip(d_int, 0, cfg.max_delta_int)
     frac = d_int >> (3 + cfg.frac_bits)
     rem = d_int & (cfg.residual_entries - 1)
-    y = lut_lookup(frac, coarse2d) * lut_lookup(rem, residual2d)
+    coarse, residual = lut_lib.exp_luts(cfg)
+    y = lut_lookup(frac, coarse) * lut_lookup(rem, residual)
     scale = jnp.float32(1 << cfg.lut_value_bits)
     y = jnp.round(y * scale) / scale
     return jnp.where(sat, 0.0, y)

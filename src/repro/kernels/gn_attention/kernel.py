@@ -26,10 +26,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.luts import SoftmaxLUTConfig, TPU_SOFTMAX_LUT
-from repro.kernels.common import exp_lut_operands, factorized_exp, snap_up_to_grid
-
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4/0.5; accept both.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from repro.kernels.common import factorized_exp, snap_up_to_grid
 
 NEG_INF = -1e30
 
@@ -38,8 +35,6 @@ def _gn_attention_kernel(
     q_ref,  # (1, 1, bq, d)
     k_ref,  # (1, 1, bk, d)
     v_ref,  # (1, 1, bk, d)
-    coarse_ref,  # (1, 128) exp LUT operand
-    residual_ref,  # (1, 128k) exp LUT operand
     o_ref,  # (1, 1, bq, d)
     acc_ref,  # (bq, d) f32 scratch
     m_ref,  # (bq, 128) f32 scratch
@@ -95,12 +90,10 @@ def _gn_attention_kernel(
         # correction for previously accumulated numerators: e^{m_old - m_new}
         # through the same LUT unit (grid-exact because both are on-grid).
         corr_delta = jnp.clip(m_new - m_old, 0.0, cfg.step * (cfg.max_delta_int + 1))
-        corr = factorized_exp(corr_delta, coarse_ref[...], residual_ref[...], cfg)
+        corr = factorized_exp(corr_delta, cfg)
         corr = jnp.where(m_old > NEG_INF / 2, corr, 0.0)       # first block: no history
 
-        y = factorized_exp(
-            jnp.maximum(m_new - s, 0.0), coarse_ref[...], residual_ref[...], cfg
-        )  # (bq, bk) numerators
+        y = factorized_exp(jnp.maximum(m_new - s, 0.0), cfg)  # (bq, bk) numerators
         y = jnp.where(mask & (m_new > NEG_INF / 2), y, 0.0)
 
         l_new = l_ref[:, :1] * corr + jnp.sum(y, axis=-1, keepdims=True)
@@ -162,7 +155,6 @@ def gn_attention_pallas(
     seq_q_valid = sq if seq_q_valid is None else seq_q_valid
     seq_k_valid = sk if seq_k_valid is None else seq_k_valid
 
-    coarse, residual = exp_lut_operands(cfg)
     grid = (b, h, sq // block_q, sk // block_k)
     kernel = functools.partial(
         _gn_attention_kernel,
@@ -185,8 +177,6 @@ def gn_attention_pallas(
             pl.BlockSpec(
                 (1, 1, block_k, d), lambda b_, h_, iq, ik: (b_, h_ // group, ik, 0)
             ),
-            pl.BlockSpec(coarse.shape, lambda b_, h_, iq, ik: (0, 0)),
-            pl.BlockSpec(residual.shape, lambda b_, h_, iq, ik: (0, 0)),
         ],
         out_specs=pl.BlockSpec(
             (1, 1, block_q, d), lambda b_, h_, iq, ik: (b_, h_, iq, 0)
@@ -197,8 +187,8 @@ def gn_attention_pallas(
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(q, k, v, coarse, residual)
+    )(q, k, v)

@@ -5,8 +5,8 @@ Fig. 4's two-stage datapath mapped to a VMEM-tiled kernel:
   stage (i)  — mean & variance over the feature axis (row-local reduction);
   stage (ii) — normalization with the CoRN reciprocal-sqrt:
                LOD == float32 exponent-field extraction (bitcast, mask),
-               compressed mantissa LUT == one-hot matmul against a (1, 128)
-               VMEM table operand, then ``iters`` mul-only Newton steps
+               compressed mantissa LUT == a select over its entries, then
+               ``iters`` mul-only Newton steps
                x <- x(1.5 - 0.5 n x^2).
 
 gamma/beta ride along as (1, cols) blocks replicated over the row grid.
@@ -19,16 +19,16 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.luts import INV_SQRT2, PAPER_RSQRT, RsqrtConfig
-from repro.kernels.common import lut_lookup, rsqrt_lut_operand
+from repro.core.luts import INV_SQRT2, PAPER_RSQRT, RsqrtConfig, rsqrt_mantissa_lut
+from repro.kernels.common import lut_lookup
 
 
-def _newton_rsqrt_block(n: jax.Array, lut2d: jax.Array, cfg: RsqrtConfig) -> jax.Array:
+def _newton_rsqrt_block(n: jax.Array, cfg: RsqrtConfig) -> jax.Array:
     """CoRN rsqrt on an (r, 1) block: LOD + mantissa LUT + NR steps."""
     bits = jax.lax.bitcast_convert_type(n, jnp.int32)
     e = ((bits >> 23) & 0xFF) - 127                      # LOD
     idx = (bits >> (23 - cfg.mantissa_bits)) & ((1 << cfg.mantissa_bits) - 1)
-    m_r = lut_lookup(idx, lut2d)
+    m_r = lut_lookup(idx, rsqrt_mantissa_lut(cfg))
     e_half = e >> 1
     odd = (e & 1).astype(jnp.float32)
     pow2 = jax.lax.bitcast_convert_type(
@@ -44,7 +44,6 @@ def _gn_layernorm_kernel(
     x_ref,
     gamma_ref,
     beta_ref,
-    lut_ref,
     o_ref,
     *,
     cfg: RsqrtConfig,
@@ -67,7 +66,7 @@ def _gn_layernorm_kernel(
     var = jnp.sum(centered * centered, axis=-1, keepdims=True) * inv_c
 
     # stage (ii): CoRN reciprocal sqrt + multiply-only output stage
-    rstd = _newton_rsqrt_block(var + 1e-8, lut_ref[...], cfg)
+    rstd = _newton_rsqrt_block(var + 1e-8, cfg)
     y = centered * rstd
     y = y * gamma_ref[...].astype(jnp.float32)
     y = y + beta_ref[...].astype(jnp.float32)
@@ -94,7 +93,6 @@ def gn_layernorm_pallas(
         valid_cols = cols
     if rows % block_rows:
         raise ValueError(f"rows {rows} not a multiple of block_rows {block_rows}")
-    lut = rsqrt_lut_operand(cfg)
     grid = (rows // block_rows,)
     return pl.pallas_call(
         functools.partial(
@@ -108,9 +106,8 @@ def gn_layernorm_pallas(
             pl.BlockSpec((block_rows, cols), lambda i: (i, 0)),
             pl.BlockSpec((1, cols), lambda i: (0, 0)),
             pl.BlockSpec((1, cols), lambda i: (0, 0)),
-            pl.BlockSpec(lut.shape, lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((block_rows, cols), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, cols), x.dtype),
         interpret=interpret,
-    )(x, gamma, beta, lut)
+    )(x, gamma, beta)

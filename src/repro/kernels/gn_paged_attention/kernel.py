@@ -41,10 +41,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.luts import SoftmaxLUTConfig, TPU_SOFTMAX_LUT
-from repro.kernels.common import exp_lut_operands, factorized_exp, snap_up_to_grid
-
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4/0.5; accept both.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from repro.kernels.common import factorized_exp, snap_up_to_grid
 
 NEG_INF = -1e30
 
@@ -61,8 +58,6 @@ def _gn_paged_attention_kernel(
     #   q_ref,  # (1, 1, bq, d) — rows [0, chunk) are the chunk queries
     #   k_ref,  # (1, 1, bs_p, d) — physical block tables_ref[n, j]
     #   v_ref,  # (1, 1, bs_p, d)
-    #   coarse_ref,  # (1, 128) exp LUT operand
-    #   residual_ref,  # (1, 128k) exp LUT operand
     #   o_ref,  # (1, 1, bq, d)
     #   acc_ref,  # (bq, d) f32 scratch
     #   m_ref,  # (bq, 128) f32 scratch
@@ -76,8 +71,7 @@ def _gn_paged_attention_kernel(
     if quantized:
         kscale_ref, vscale_ref = refs[:2]
         refs = refs[2:]
-    (q_ref, k_ref, v_ref, coarse_ref, residual_ref,
-     o_ref, acc_ref, m_ref, l_ref) = refs
+    q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
     n = pl.program_id(0)
     j = pl.program_id(2)
     nj = pl.num_programs(2)
@@ -124,12 +118,10 @@ def _gn_paged_attention_kernel(
         m_new = jnp.where(any_valid | (m_old > NEG_INF / 2), m_new, m_old)
 
         corr_delta = jnp.clip(m_new - m_old, 0.0, cfg.step * (cfg.max_delta_int + 1))
-        corr = factorized_exp(corr_delta, coarse_ref[...], residual_ref[...], cfg)
+        corr = factorized_exp(corr_delta, cfg)
         corr = jnp.where(m_old > NEG_INF / 2, corr, 0.0)
 
-        y = factorized_exp(
-            jnp.maximum(m_new - s, 0.0), coarse_ref[...], residual_ref[...], cfg
-        )
+        y = factorized_exp(jnp.maximum(m_new - s, 0.0), cfg)
         y = jnp.where(mask & (m_new > NEG_INF / 2), y, 0.0)
 
         l_new = l_ref[:, :1] * corr + jnp.sum(y, axis=-1, keepdims=True)
@@ -182,7 +174,6 @@ def gn_paged_attention_pallas(
         sm_scale = d**-0.5
     quantized = k_scale is not None
 
-    coarse, residual = exp_lut_operands(cfg)
     grid = (n, h, max_bt)
     kernel = functools.partial(
         _gn_paged_attention_kernel,
@@ -211,8 +202,6 @@ def gn_paged_attention_pallas(
             pl.BlockSpec((1, 1, bq, d), lambda n_, h_, j, *_: (n_, h_, 0, 0)),
             pl.BlockSpec((1, 1, bs_p, d), kv_index),
             pl.BlockSpec((1, 1, bs_p, d), kv_index),
-            pl.BlockSpec(coarse.shape, lambda n_, h_, j, *_: (0, 0)),
-            pl.BlockSpec(residual.shape, lambda n_, h_, j, *_: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, d), lambda n_, h_, j, *_: (n_, h_, 0, 0)),
         scratch_shapes=[
@@ -231,8 +220,8 @@ def gn_paged_attention_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(*scalars, q, k_arena, v_arena, coarse, residual)
+    )(*scalars, q, k_arena, v_arena)

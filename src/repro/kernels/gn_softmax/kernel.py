@@ -4,9 +4,9 @@ TPU adaptation of the paper's Fig. 3 datapath (see DESIGN.md §2):
 
 * rows stream through VMEM in ``(block_rows, cols)`` tiles — the Pallas
   analogue of the RTL's N-cycle streaming pipeline;
-* the two exponential LUTs (7-entry coarse, ``R·2^f``-entry residual) ride in
-  as (1, 128) VMEM operands and are applied as **one-hot × LUT matmuls** — the
-  MXU-idiomatic equivalent of a ROM lookup (TPU has no cheap per-lane gather);
+* the two exponential LUTs (7-entry coarse, ``R·2^f``-entry residual) are
+  fixed by the LUT config and applied as **selects over their entries** —
+  the VPU equivalent of a ROM lookup (TPU has no cheap per-lane gather);
 * the single per-row reciprocal (FxP_Div in silicon) is one VPU ``1/z``;
   numerator and denominator use the same approximated ``y``, so ``Σp = 1``.
 
@@ -22,12 +22,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.luts import SoftmaxLUTConfig, TPU_SOFTMAX_LUT
-from repro.kernels.common import exp_lut_operands, factorized_exp
+from repro.kernels.common import factorized_exp
 
 
-def _gn_softmax_kernel(
-    x_ref, coarse_ref, residual_ref, o_ref, *, cfg: SoftmaxLUTConfig, valid_cols: int
-):
+def _gn_softmax_kernel(x_ref, o_ref, *, cfg: SoftmaxLUTConfig, valid_cols: int):
     x = x_ref[...].astype(jnp.float32)
     rows, cols = x.shape
 
@@ -43,7 +41,7 @@ def _gn_softmax_kernel(
     delta = jnp.maximum(m - x, 0.0)
 
     # (ii) exponential stage: Δ-grid quantization + two-LUT factorization
-    y = factorized_exp(delta, coarse_ref[...], residual_ref[...], cfg)
+    y = factorized_exp(delta, cfg)
     y = jnp.where(valid, y, 0.0)
 
     # (iii) normalization stage: one reciprocal per row, shared numerator /
@@ -74,17 +72,12 @@ def gn_softmax_pallas(
         valid_cols = cols
     if rows % block_rows:
         raise ValueError(f"rows {rows} not a multiple of block_rows {block_rows}")
-    coarse, residual = exp_lut_operands(cfg)
     grid = (rows // block_rows,)
     return pl.pallas_call(
         functools.partial(_gn_softmax_kernel, cfg=cfg, valid_cols=valid_cols),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, cols), lambda i: (i, 0)),
-            pl.BlockSpec(coarse.shape, lambda i: (0, 0)),
-            pl.BlockSpec(residual.shape, lambda i: (0, 0)),
-        ],
+        in_specs=[pl.BlockSpec((block_rows, cols), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, cols), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, cols), x.dtype),
         interpret=interpret,
-    )(x, coarse, residual)
+    )(x)

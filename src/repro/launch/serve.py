@@ -41,11 +41,14 @@ if "--smoke" in _sys.argv:
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import store
 from repro.configs.registry import get_config, list_archs, reduce_config
 from repro.data.synthetic import DataConfig, batch_at
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.transformer import make_model
+from repro.parallel.sharding import make_slot_mesh
 from repro.serve.engine import (
     ContinuousEngine,
     ServeConfig,
@@ -81,24 +84,28 @@ def _serve_static(model, cfg, params, args, scfg):
           f"(softmax={cfg.softmax_impl}, norm={cfg.norm_impl})")
 
 
-def _serve_continuous(model, cfg, params, args, scfg):
+def serve_continuous(model, params, args, scfg, **engine_kw):
+    """Serve the staggered workload ``args`` describes through
+    ``ContinuousEngine`` (``engine_kw`` adds constructor options), print the
+    run's counts, and check the compile counters against the horizon-bucket
+    bound.  Returns ``(engine, requests, completions, wall_s)``; ``wall_s``
+    covers compilation on a cold engine.  ``--continuous`` and
+    ``chip_smoke.py`` both serve through here."""
     reqs = staggered_requests(
-        cfg, n_requests=args.requests, base_len=args.prompt_len,
+        model.cfg, n_requests=args.requests, base_len=args.prompt_len,
         max_new_tokens=args.new_tokens, stagger=args.stagger, seed=11,
     )
-    max_seq = required_max_seq(reqs)
     num_slots = round_slots_to_devices(args.num_slots, args.devices)
     engine = ContinuousEngine(model, params, num_slots=num_slots,
-                              max_seq=max_seq, cfg=scfg, chunk=args.chunk,
-                              devices=args.devices)
+                              max_seq=required_max_seq(reqs), cfg=scfg,
+                              chunk=args.chunk, devices=args.devices,
+                              **engine_kw)
     t0 = time.time()
     comps = engine.run(reqs)
-    dt = time.time() - t0
+    wall = time.time() - t0
     m = engine.metrics()
-    gen_tok = m["generated_tokens"]
-    print(f"continuous: {len(comps)} requests, {gen_tok} tokens in {dt:.2f}s "
-          f"({gen_tok/dt:.1f} tok/s)  slots={num_slots} "
-          f"util={m['mean_slot_utilization']:.2f}")
+    print(f"continuous: {len(comps)} requests, {m['generated_tokens']} tokens, "
+          f"slots={num_slots} util={m['mean_slot_utilization']:.2f}")
     if m["num_devices"] > 1:
         print(f"slot pool sharded over {m['num_devices']} devices "
               f"({m['per_device_slots']} slots each): admissions/device "
@@ -129,7 +136,7 @@ def _serve_continuous(model, cfg, params, args, scfg):
         print(f"  req {c.request_id}: prompt {len(c.prompt_tokens)} "
               f"+{len(c.new_tokens)} [{c.finish_reason}]  "
               f"arrive@{c.arrival_step} admit@{c.admit_step} "
-              f"finish@{c.finish_step}  latency {c.latency_s*1e3:.0f}ms")
+              f"finish@{c.finish_step}")
 
     # Counters are explicit trace counts (always ints).  Slab engines
     # compile the fused step exactly once (decode fast path may be unused);
@@ -138,14 +145,27 @@ def _serve_continuous(model, cfg, params, args, scfg):
     # paths.
     if m["kv_paged"]:
         grid = m["horizon_bucket_grid"]
-        assert m["fused_step_compilations"] == len(m["fused_buckets"]) <= len(grid), \
-            "fused step recompiled beyond the bucket bound!"
-        assert m["decode_compilations"] == len(m["decode_buckets"]) <= len(grid), \
-            "decode step recompiled beyond the bucket bound!"
+        if not m["fused_step_compilations"] == len(m["fused_buckets"]) <= len(grid):
+            raise RuntimeError("fused step recompiled beyond the bucket bound!")
+        if not m["decode_compilations"] == len(m["decode_buckets"]) <= len(grid):
+            raise RuntimeError("decode step recompiled beyond the bucket bound!")
     else:
-        assert m["fused_step_compilations"] == 1, "fused step recompiled!"
-        assert m["decode_compilations"] in (0, 1), "decode step recompiled!"
-    assert m["prefill_compilations"] == 0, "per-prompt-length prefill is back?!"
+        if m["fused_step_compilations"] != 1:
+            raise RuntimeError("fused step recompiled!")
+        if m["decode_compilations"] not in (0, 1):
+            raise RuntimeError("decode step recompiled!")
+    if m["prefill_compilations"] != 0:
+        raise RuntimeError("per-prompt-length prefill is back?!")
+    return engine, reqs, comps, wall
+
+
+def _serve_continuous(model, params, args, scfg):
+    engine, reqs, comps, wall = serve_continuous(model, params, args, scfg)
+    gen_tok = engine.metrics()["generated_tokens"]
+    print(f"{gen_tok} tokens in {wall:.2f}s ({gen_tok/wall:.1f} tok/s); "
+          "latency ms: " + " ".join(
+              f"{c.request_id}:{c.latency_s*1e3:.0f}"
+              for c in sorted(comps, key=lambda c: c.request_id)))
     if scfg.temperature == 0:
         ref = static_reference(model, params, reqs, scfg)
         same = all(np.array_equal(c.tokens, ref[c.request_id]) for c in comps)
@@ -153,7 +173,7 @@ def _serve_continuous(model, cfg, params, args, scfg):
         assert same, "continuous batching diverged from the static oracle"
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="internlm2-1.8b", choices=list_archs())
     ap.add_argument("--smoke", action="store_true", help="reduced config (CPU)")
@@ -176,21 +196,42 @@ def main(argv=None):
     ap.add_argument("--devices", type=int, default=1,
                     help="continuous: shard the slot pool over N devices "
                          "(--smoke forces N host-platform devices itself)")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def build(args):
+    """The config, model, params (PRNGKey(0), or ``--ckpt``) and serve config
+    that ``args`` names.  With ``--devices N > 1`` the params are made
+    straight onto the replicated slot-mesh sharding the engine serves from,
+    so no device holds a second copy."""
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = reduce_config(cfg)
     model = make_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    replicated = (NamedSharding(make_slot_mesh(args.devices), P())
+                  if args.devices > 1 else None)
+    key = jax.random.PRNGKey(0)
     if args.ckpt:
         step = store.latest_step(args.ckpt)
-        (params,), _ = store.restore(args.ckpt, step, (params,))
+        (params,), _ = store.restore(args.ckpt, step,
+                                     (jax.eval_shape(model.init, key),))
+        params = jax.device_put(params, replicated)
         print(f"restored checkpoint step {step} from {args.ckpt}")
-
+    else:
+        # one jitted init whatever the device count (eager init rounds some
+        # values differently), so --devices N serves --devices 1's params
+        placed = {} if replicated is None else {"out_shardings": replicated}
+        params = jax.jit(model.init, **placed)(key)
     scfg = ServeConfig(max_new_tokens=args.new_tokens, temperature=args.temperature)
+    return cfg, model, params, scfg
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    use_compile_cache()
+    cfg, model, params, scfg = build(args)
     if args.continuous:
-        _serve_continuous(model, cfg, params, args, scfg)
+        _serve_continuous(model, params, args, scfg)
     else:
         _serve_static(model, cfg, params, args, scfg)
 

@@ -29,6 +29,7 @@ import jax
 from repro.checkpoint import store
 from repro.configs.registry import get_config, reduce_config
 from repro.data.synthetic import DataConfig, batch_at
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.transformer import make_model
 from repro.parallel.sharding import use_sharding
 from repro.train.loop import make_train_step
@@ -50,6 +51,7 @@ def main(argv=None):
     ap.add_argument("--grad-compression", type=int, default=None)
     ap.add_argument("--straggler-factor", type=float, default=3.0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

@@ -87,13 +87,12 @@ def self_attention(
     if cfg.use_pallas:
         from repro.kernels.gn_attention.ops import gn_attention
 
-        interp = jax.devices()[0].platform != "tpu"
         out = gn_attention(
             q.transpose(0, 2, 1, 3),
             k.transpose(0, 2, 1, 3),
             v.transpose(0, 2, 1, 3),
             causal=causal,
-            interpret=interp,
+            interpret=cfg.pallas_interpret,
         ).transpose(0, 2, 1, 3)
     elif _use_chunked(cfg, s):
         from repro.models.chunked_attention import chunked_self_attention
@@ -548,12 +547,11 @@ def attn_paged_chunk(cfg: ModelConfig, p: dict, arena_k, arena_v, x, positions,
     if path == "pallas":
         # single-chip TPU hot path: the Pallas kernel chases the block table
         # with scalar-prefetched index maps instead of materializing the
-        # gathered stream (interpret-mode on CPU); same GN datapath, tiled.
+        # gathered stream; same GN datapath, tiled.
         # Chunked queries ride the same kernel (causal intra-chunk mask);
         # int8 arenas dequantize in-kernel, per block, after the DMA.
         from repro.kernels.gn_paged_attention.ops import gn_paged_attention_chunk
 
-        interp = jax.devices()[0].platform != "tpu"
         out = gn_paged_attention_chunk(
             q,
             flat_k.reshape(nb, bs, kv, dh),
@@ -561,7 +559,7 @@ def attn_paged_chunk(cfg: ModelConfig, p: dict, arena_k, arena_v, x, positions,
             tables,
             positions,
             n_valid,
-            interpret=interp,
+            interpret=cfg.pallas_interpret,
             scales=rd_scales,
         ).reshape(b, c_len, cfg.q_features)
         if probe:

@@ -172,11 +172,17 @@ def _static_jits(model: Model, max_seq: int):
     return cache[key], cache["decode"]
 
 
-def generate(model: Model, params, batch: dict, cfg: ServeConfig):
+def generate(model: Model, params, batch: dict, cfg: ServeConfig, *,
+             forced=None, return_logits: bool = False):
     """batch['tokens']: (B, S_prompt) -> (B, S_prompt + max_new) tokens.
 
     Prefill once, then ``max_new_tokens`` decode steps under jit (the decode
     step is compiled once; positions are traced scalars).
+
+    ``forced`` (B, S_prompt + max_new) teacher-forces the continuation: step
+    i feeds ``forced[:, S_prompt + i]`` in place of the chosen token, so the
+    logits are the model's on given tokens.  ``return_logits`` also returns
+    the (B, max_new, vocab) f32 logits each new token was chosen from.
     """
     tokens = batch["tokens"]
     b, s = tokens.shape
@@ -190,8 +196,13 @@ def generate(model: Model, params, batch: dict, cfg: ServeConfig):
     out = jnp.zeros((b, max_seq), jnp.int32)
     out = jax.lax.dynamic_update_slice(out, tokens.astype(jnp.int32), (0, 0))
 
+    rows = []
     for i in range(cfg.max_new_tokens):
-        if cfg.temperature > 0:
+        if return_logits:
+            rows.append(last_logits.astype(jnp.float32))
+        if forced is not None:
+            nxt = jnp.asarray(forced, jnp.int32)[:, s + i]
+        elif cfg.temperature > 0:
             key, sub = jax.random.split(key)
             nxt = jax.random.categorical(sub, last_logits / cfg.temperature, axis=-1)
         else:
@@ -200,6 +211,8 @@ def generate(model: Model, params, batch: dict, cfg: ServeConfig):
         out = jax.lax.dynamic_update_slice(out, nxt, (0, s + i))
         logits_step, cache = decode(params, cache, nxt, jnp.int32(s + i))
         last_logits = logits_step[:, 0]
+    if return_logits:
+        return out, jnp.stack(rows, axis=1)
     return out
 
 
@@ -214,33 +227,48 @@ def perplexity(model: Model, params, batch: dict) -> float:
 
 
 def static_reference(model: Model, params, requests: Sequence[Request],
-                     cfg: ServeConfig) -> dict[int, np.ndarray]:
+                     cfg: ServeConfig, *, forced: Optional[dict] = None,
+                     return_logits: bool = False):
     """Serve ``requests`` through the static engine: group by (prompt_len,
     max_new_tokens) in FCFS order, one ``generate`` call per group.  Returns
     request id -> full (prompt + generated) token array, truncated at a
     request's stop token if it has one (the static engine itself always
     decodes the full budget).  This is both the greedy-identity oracle and
     the static baseline in benchmarks — greedy only, since sampled paths use
-    different key streams per engine."""
+    different key streams per engine.
+
+    ``forced`` maps request id -> full token array to teacher-force, and
+    ``return_logits`` adds a second dict, request id -> (n_new, vocab) f32
+    logits each new token was chosen from (see ``generate``)."""
     if any(r.temperature not in (None, 0, 0.0) for r in requests) or cfg.temperature:
         raise ValueError("static_reference is a greedy oracle (temperature 0 only)")
     groups: dict[tuple, list[Request]] = {}
     for req in requests:
         groups.setdefault((req.prompt_len, req.max_new_tokens), []).append(req)
     out: dict[int, np.ndarray] = {}
+    out_logits: dict[int, np.ndarray] = {}
     for (plen, max_new), reqs in groups.items():
         batch = {"tokens": jnp.stack([jnp.asarray(r.tokens, jnp.int32) for r in reqs])}
         for k in reqs[0].extras:
             batch[k] = jnp.stack([jnp.asarray(r.extras[k]) for r in reqs])
         gcfg = dataclasses.replace(cfg, max_new_tokens=max_new)
-        toks = np.asarray(generate(model, params, batch, gcfg))
-        for r, row in zip(reqs, toks):
+        teacher = (None if forced is None else
+                   np.stack([np.asarray(forced[r.id], np.int32) for r in reqs]))
+        res = generate(model, params, batch, gcfg, forced=teacher,
+                       return_logits=return_logits)
+        toks, logits = res if return_logits else (res, None)
+        toks = np.asarray(toks)
+        logits = None if logits is None else np.asarray(logits)
+        for i, (r, row) in enumerate(zip(reqs, toks)):
+            n_new = max_new
             if r.stop_token is not None:
                 hits = np.nonzero(row[plen:] == r.stop_token)[0]
                 if hits.size:
-                    row = row[: plen + hits[0] + 1]
-            out[r.id] = row
-    return out
+                    n_new = int(hits[0]) + 1
+            out[r.id] = row[: plen + n_new]
+            if logits is not None:
+                out_logits[r.id] = logits[i, :n_new]
+    return (out, out_logits) if return_logits else out
 
 
 # ------------------------------------------------------------ continuous ---
@@ -305,6 +333,12 @@ class ContinuousEngine:
     ``Model.prefill_chunk``.  MoE chunked prefill is the one exception:
     GShard capacity dropping depends on the dispatch group, so a chunked
     pass can route borderline tokens differently than a monolithic one.
+
+    ``record_logits=True`` keeps, per request, the held logits each new
+    token was chosen from, as ``Completion.logits`` (n_new, vocab): one
+    extra device->host copy of the (num_slots, vocab) logits per decoding
+    tick.  This is how a numerical check compares the served logits with
+    ``static_reference(..., return_logits=True)``.
     """
 
     def __init__(self, model: Model, params, num_slots: int, max_seq: int,
@@ -318,8 +352,10 @@ class ContinuousEngine:
                  kv_dtype: str = "fp", sentinels: Optional[bool] = None,
                  fault_retry_budget: int = 3,
                  clip_fallback_frac: float = 0.5, clip_patience: int = 3,
-                 device_loss_min_slots: int = 2):
-        self.model, self.params, self.cfg = model, params, cfg
+                 device_loss_min_slots: int = 2,
+                 record_logits: bool = False):
+        self.model, self.cfg = model, cfg
+        self.record_logits = bool(record_logits)
         self.num_slots, self.max_seq = int(num_slots), int(max_seq)
         self.chunk = int(chunk)
         win = model.cfg.sliding_window or 0
@@ -376,6 +412,10 @@ class ContinuousEngine:
             self._sh_rep = NamedSharding(self.mesh, P())              # replicated
         else:
             self._sh_slot = self._sh_row = self._sh_rep = None
+        # Params are placed once, replicated over the mesh: every tick then
+        # reads them in place, where params left on one device would be
+        # copied (or refused for mixing device sets) at each dispatch.
+        self.params = self._put(params, self._sh_rep)
         # Block-paged KV wherever the family's cache is pageable (dense/moe/
         # encdec/vlm full-attention KV, MLA latents): HBM scales with live
         # tokens, admission gates on free blocks.  SSM/hybrid carries and
@@ -548,6 +588,7 @@ class ContinuousEngine:
         self._key = self._put(jax.random.PRNGKey(self.cfg.seed), self._sh_rep)
         self.step_count = 0
         self.completions: list[Completion] = []
+        self._logit_rows: dict[int, list] = {}  # record_logits: id -> rows
         self._active_steps = 0   # sum over decode steps of active-slot count
         self._decode_steps = 0
         self._fused_ticks = 0    # ticks that carried at least one prefill lane
@@ -1060,6 +1101,10 @@ class ContinuousEngine:
             self.pool.device_of(slot),
         )
 
+    def _recorded_logits(self, req_id: int) -> Optional[np.ndarray]:
+        rows = self._logit_rows.pop(req_id, None)
+        return np.stack(rows) if rows else None
+
     def _finish(self, slot: int, reason: str) -> None:
         st = self._slots[slot]
         # written >= prompt_len: a recompute-resumed slot prefilled past the
@@ -1085,6 +1130,7 @@ class ContinuousEngine:
             finish_time=now,
             req_class=st.req.req_class,
             preemptions=st.preemptions,
+            logits=self._recorded_logits(st.req.id),
         ))
         self.event_log.append(("finish", self.step_count, st.req.id, reason))
         self._slots[slot] = None
@@ -1393,6 +1439,10 @@ class ContinuousEngine:
             if sliced is None:
                 sliced = self._tables_sliced[bucket] = self._tables_dev[:, :bucket]
             paged_args = (sliced,)
+        # the logits this tick's decoders choose from, fetched before the
+        # tick donates them
+        held = (np.asarray(self._last_logits)
+                if self.record_logits and decoders else None)
         if prefills:
             chunk_toks = np.zeros((self.num_slots, self.chunk), np.int32)
             n_valid = np.ones(self.num_slots, np.int32)
@@ -1469,6 +1519,8 @@ class ContinuousEngine:
                 continue
             tok = int(toks[slot])
             st.generated.append(tok)
+            if held is not None:
+                self._logit_rows.setdefault(st.req.id, []).append(held[slot])
             if len(st.generated) == 1:
                 st.first_token_step = self.step_count
                 st.first_token_time = time.time()
@@ -1498,6 +1550,20 @@ class ContinuousEngine:
             if self.step_count > budget:
                 raise RuntimeError("ContinuousEngine failed to drain workload")
         return self.completions
+
+    def compiled_tick_text(self, requests: Sequence[Request]) -> str:
+        """Compiled text of the first fused tick that serving ``requests``
+        dispatches (reset, replay with signature capture, compile that
+        signature): where a caller checks what the tick's program holds,
+        such as the Pallas read's ``tpu_custom_call``."""
+        self.reset()
+        self._fused.capture_avals = {}
+        try:
+            self.run(requests)
+            avals = next(iter(self._fused.capture_avals.values()))
+        finally:
+            self._fused.capture_avals = None
+        return self._fused.jitted.lower(*avals).compile().as_text()
 
     # ------------------------------------------------------------ snapshots --
     # Crash-consistent engine snapshots over checkpoint/store.py's atomic

@@ -114,6 +114,9 @@ class Completion:
     finish_time: float
     req_class: str = "interactive"
     preemptions: int = 0  # times this request was evicted and later resumed
+    # (n_new, vocab) held logits each new token was chosen from, kept only
+    # by an engine built with record_logits=True
+    logits: Optional[np.ndarray] = None
 
     def __post_init__(self):
         if self.finish_reason not in FINISH_REASONS:

@@ -8,7 +8,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import luts
 from repro.core.luts import SoftmaxLUTConfig
+from repro.kernels.common import lut_lookup
 from repro.kernels.gn_attention.ops import gn_attention
 from repro.kernels.gn_attention.ref import gn_attention_ref
 from repro.kernels.gn_layernorm.ops import gn_layernorm, gn_rmsnorm
@@ -21,6 +23,21 @@ KEY = jax.random.PRNGKey(7)
 
 def _rand(shape, dtype=jnp.float32, scale=3.0, key=KEY):
     return (jax.random.normal(key, shape) * scale).astype(dtype)
+
+
+@pytest.mark.parametrize("table", ["coarse", "residual", "rsqrt"])
+def test_lut_lookup_bit_equal_to_table(table):
+    """The in-kernel ROM lookup returns the table's entries bit for bit, and
+    0 past its end."""
+    coarse, residual = luts.exp_luts(luts.TPU_SOFTMAX_LUT)
+    values = {"coarse": coarse, "residual": residual,
+              "rsqrt": luts.rsqrt_mantissa_lut(luts.PAPER_RSQRT)}[table]
+    n = values.shape[0]
+    idx = jnp.arange(8 * 128, dtype=jnp.int32).reshape(8, 128) % (n + 3)
+    want = np.concatenate([values, np.zeros(3, np.float32)])[np.asarray(idx)]
+    got = np.asarray(lut_lookup(idx, values))
+    assert got.dtype == np.float32
+    np.testing.assert_array_equal(got.view(np.int32), want.view(np.int32))
 
 
 SOFTMAX_SHAPES = [

@@ -23,6 +23,8 @@ Pinned invariants:
      and per-tick attended width under horizon bucketing stays below the
      full max_bt stream.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -53,7 +55,9 @@ CHUNK = 4
 
 @pytest.fixture(scope="module")
 def dense():
-    cfg = reduce_config(get_config("internlm2-1.8b"))
+    # pallas_interpret: the forced 'pallas' read runs in the interpreter here
+    cfg = dataclasses.replace(reduce_config(get_config("internlm2-1.8b")),
+                              pallas_interpret=True)
     model = make_model(cfg)
     return cfg, model, model.init(jax.random.PRNGKey(0))
 
@@ -407,6 +411,31 @@ def test_chunked_query_kernel_sum_to_one_through_block_table(block_size):
     c = q.shape[1]
     lane_ok = np.arange(c)[None, :] < np.asarray(n_valid)[:, None]
     np.testing.assert_allclose(np.asarray(out)[lane_ok], 1.0, atol=1e-5)
+
+
+def test_pallas_read_off_tpu_is_not_interpreted_unasked(dense):
+    """No hidden fallback: off a TPU, the 'pallas' read of a config that did
+    not ask for the interpreter fails to lower instead of interpreting."""
+    if jax.default_backend() == "tpu":
+        pytest.skip("the kernel compiles on a TPU")
+    cfg = dataclasses.replace(dense[0], pallas_interpret=False)
+    nb, bs, n = 8, CHUNK, 2
+    p = {name: jnp.zeros(shape, jnp.float32) for name, shape in (
+        ("wq", (cfg.d_model, cfg.q_features)),
+        ("wk", (cfg.d_model, cfg.kv_features)),
+        ("wv", (cfg.d_model, cfg.kv_features)),
+        ("wo", (cfg.q_features, cfg.d_model)))}
+    arena = jnp.zeros((nb, bs, cfg.n_kv_heads, cfg.head_dim), jnp.float32)
+    x = jnp.zeros((n, CHUNK, cfg.d_model), jnp.float32)
+    tables = jnp.arange(nb, dtype=jnp.int32).reshape(n, nb // n)
+    per_seq = jnp.full((n,), CHUNK, jnp.int32)
+    attention_mod.FORCE_PAGED_READ = "pallas"
+    try:
+        with pytest.raises(ValueError, match="interpret"):
+            attention_mod.attn_paged_chunk(cfg, p, arena, arena, x, per_seq,
+                                           per_seq, tables)
+    finally:
+        attention_mod.FORCE_PAGED_READ = None
 
 
 def test_paged_chunk_pallas_read_matches_gathered(dense):

@@ -29,6 +29,8 @@ Pinned invariants:
      identical hit/fork/evict sequence (the LRU clock is an op counter,
      never wall time).
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -57,7 +59,9 @@ requires_mesh = pytest.mark.skipif(
 
 @pytest.fixture(scope="module")
 def dense():
-    cfg = reduce_config(get_config("internlm2-1.8b"))
+    # pallas_interpret: the forced 'pallas' read runs in the interpreter here
+    cfg = dataclasses.replace(reduce_config(get_config("internlm2-1.8b")),
+                              pallas_interpret=True)
     model = make_model(cfg)
     return cfg, model, model.init(jax.random.PRNGKey(0))
 

@@ -258,12 +258,7 @@ def test_mla_paged_gn_read_sums_to_one_over_int8_blocks(seed, bs_mult):
 
 # --------------------------------- norm-error study: measured vs bound pin --
 def test_paged_read_norm_error_within_analytic_bound():
-    import pathlib
-    import sys
-    ex = str(pathlib.Path(__file__).resolve().parents[1] / "examples")
-    if ex not in sys.path:
-        sys.path.insert(0, ex)
-    from norm_error_study import paged_int8_read_norm_error
+    from repro.serve.norm_error import paged_int8_read_norm_error
 
     for kd in ("fp", "int8"):
         measured, bound, t = paged_int8_read_norm_error(kv_dtype=kd)

@@ -190,6 +190,40 @@ def test_single_device_pools_keep_global_fifo(dense):
 
 # ----------------------------------------------------- 2-device SPMD tests ---
 @requires_mesh
+def test_sharded_params_replicated_once(dense):
+    """devices=2: every params leaf a tick receives sits on the engine's
+    replicated NamedSharding, and every later tick receives those same
+    buffers under a transfer guard — params move once, at construction,
+    never per tick."""
+    cfg, model, params = dense
+    reqs = _mixed_requests(cfg)
+    engine = ContinuousEngine(model, params, num_slots=2,
+                              max_seq=required_max_seq(reqs),
+                              cfg=ServeConfig(), chunk=CHUNK, devices=2)
+    received = []
+
+    def recording(jitted):
+        def call(tick_params, *args):
+            if received:
+                with jax.transfer_guard("disallow"):
+                    out = jitted(tick_params, *args)
+            else:
+                out = jitted(tick_params, *args)
+            received.append(jax.tree.leaves(tick_params))
+            return out
+        return call
+
+    for step in (engine._fused, engine._decode):
+        step._jitted = recording(step._jitted)
+    engine.run(reqs)
+    assert len(received) >= 2
+    first = received[0]
+    assert all(leaf.sharding == engine._sh_rep for leaf in first)
+    for leaves in received[1:]:
+        assert all(a is b for a, b in zip(leaves, first))
+
+
+@requires_mesh
 @pytest.mark.parametrize("family", ["dense", "mla"])
 @pytest.mark.parametrize("paged", [True, False], ids=["paged", "slab"])
 def test_sharded_greedy_identity_and_counters(dense, mla, family, paged):
@@ -275,6 +309,40 @@ def test_devices_exceeding_visible_raises():
     with pytest.raises(ValueError, match="devices"):
         ContinuousEngine(model, params, num_slots=jax.device_count() + 1,
                          max_seq=16, devices=jax.device_count() + 1)
+
+
+_BUILD_CHECK = """
+import jax
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.launch import serve
+from repro.serve.engine import ContinuousEngine
+args = serve.parse_args(["--smoke", "--continuous", "--devices", "2"])
+cfg, model, params, scfg = serve.build(args)
+leaves = jax.tree.leaves(params)
+engine = ContinuousEngine(model, params, num_slots=2, max_seq=64, cfg=scfg,
+                          chunk=4, devices=2)
+rep = NamedSharding(engine.mesh, P())
+assert all(x.sharding == rep and x.is_fully_replicated for x in leaves)
+assert all(a is b for a, b in zip(jax.tree.leaves(engine.params), leaves))
+single = serve.build(serve.parse_args(["--smoke", "--continuous"]))[2]
+ref = jax.tree.leaves(single)
+assert all((a == b).all() for a, b in zip(leaves, ref))
+print("BUILD_OK")
+"""
+
+
+def test_build_makes_params_on_the_replicated_mesh():
+    """``launch/serve.py --devices 2`` makes the params straight onto the
+    replicated slot-mesh sharding, with the values ``--devices 1`` makes,
+    and the engine serves from those buffers without a copy: no
+    device ever holds a second set.  Runs in a child process with two
+    forced host devices, so it runs on one-device hosts too."""
+    env = dict(os.environ, PYTHONPATH=str(REPO / "src"),
+               XLA_FLAGS="--xla_force_host_platform_device_count=2",
+               JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, "-c", _BUILD_CHECK], env=env,
+                       cwd=REPO, capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0 and "BUILD_OK" in r.stdout, r.stderr[-3000:]
 
 
 # ------------------------------------------------- subprocess SPMD driver ---

@@ -167,6 +167,7 @@ class TestFaultTolerance:
     def _run(self, outdir, extra):
         env = dict(os.environ)
         env["PYTHONPATH"] = str(REPO / "src")
+        env["JAX_ENABLE_COMPILATION_CACHE"] = "false"  # tests keep no cache
         cmd = [
             sys.executable, "-m", "repro.launch.train",
             "--arch", "internlm2-1.8b", "--smoke",
@@ -195,3 +196,28 @@ class TestFaultTolerance:
         assert last_loss(tmp_path / "full") == pytest.approx(
             last_loss(tmp_path / "ft"), rel=1e-5
         )
+
+
+class TestCompileCache:
+    """The entry points' cache placement, checked without turning it on."""
+
+    def _calls(self, monkeypatch):
+        from repro.launch import compile_cache
+
+        calls = []
+        monkeypatch.setattr(compile_cache.jax.config, "update",
+                            lambda name, value: calls.append((name, value)))
+        return compile_cache, calls
+
+    def test_env_dir_is_left_to_jax(self, monkeypatch, tmp_path):
+        compile_cache, calls = self._calls(monkeypatch)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.use_compile_cache() == str(tmp_path)
+        assert calls == []
+
+    def test_unset_env_uses_fixed_checkout_dir(self, monkeypatch):
+        compile_cache, calls = self._calls(monkeypatch)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        path = str(REPO / ".jax_cache")
+        assert compile_cache.use_compile_cache() == path
+        assert calls == [("jax_compilation_cache_dir", path)]
