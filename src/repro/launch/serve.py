@@ -114,6 +114,8 @@ def serve_continuous(model, params, args, scfg, **engine_kw):
           f"{m['decode_compilations']}x, per-prompt-length prefill "
           f"{m['prefill_compilations']}x  (chunk={m['chunk']}, intake "
           f"padding {m['intake_padding']} tok)")
+    print("host phases of step(), ms: " + ", ".join(
+        f"{name} {s * 1e3:.1f}" for name, s in m["host_phase_s"].items()))
     if m["kv_paged"]:
         print(f"paged reads: {m['read_path']}; horizon buckets "
               f"{m['horizon_buckets']} of grid {m['horizon_bucket_grid']} "

@@ -14,6 +14,9 @@ from repro.configs.base import ModelConfig
 from repro.core import get_softmax
 from repro.models.layers import ParamSpec
 from repro.models.rope import apply_rope
+from repro.serve.tracing import (
+    ATTN_PROJ, GN_PROBE, GN_SOFTMAX, KV_WRITE, PAGED_READ,
+)
 
 NEG_INF = -1e30
 
@@ -246,6 +249,7 @@ FORCE_PAGED_READ: str | None = None
 SCALE_SANITY_MAX = 1e4
 
 
+@jax.named_scope(GN_PROBE)
 def _probe_sum_residual(pmat, scores, out, valid, lane_ok):
     """GN sentinel channel 0, per slot: the Σp residual of this layer's
     paged attention read, with nonfinite laundering ruled out.
@@ -273,6 +277,7 @@ def _probe_sum_residual(pmat, scores, out, valid, lane_ok):
     return jnp.where(bad | obad, jnp.inf, res)
 
 
+@jax.named_scope(GN_PROBE)
 def paged_probe_word(probe0, positions, n_valid, tables, block_size: int,
                      rd_scales, clip_tok):
     """Assemble one layer's (N, 3) sentinel health word.
@@ -445,7 +450,8 @@ def _stream_paged_tiles(cfg: ModelConfig, qg, arena_k, arena_v, tables, rows,
         # int8 blocks gathered first, dequantized per block after the gather
         v_at = v_at.astype(dt) * v_scale[tables].astype(dt)[..., None, None, None]
     v_at = v_at.reshape(n, -1, kv, dh)
-    pmat = get_softmax(cfg.softmax_impl)(scores).astype(v_at.dtype)
+    with jax.named_scope(GN_SOFTMAX):
+        pmat = get_softmax(cfg.softmax_impl)(scores).astype(v_at.dtype)
     out = jnp.einsum("bkgst,btkd->bskgd", pmat, v_at)
     if probe_nv is not None:
         lane_ok = jnp.arange(rows.shape[1])[None, :] < probe_nv[:, None]
@@ -507,40 +513,42 @@ def attn_paged_chunk(cfg: ModelConfig, p: dict, arena_k, arena_v, x, positions,
     nb, bs = arena_k.shape[:2]
     offs = jnp.arange(c_len)
     rows = positions[:, None] + offs[None, :]  # (N, C) absolute positions
-    q = _split_heads(jnp.einsum("bsd,df->bsf", x, p["wq"].astype(dt)), cfg.n_heads, cfg.head_dim)
-    k_new = _split_heads(jnp.einsum("bsd,df->bsf", x, p["wk"].astype(dt)), cfg.n_kv_heads, cfg.head_dim)
-    v_new = _split_heads(jnp.einsum("bsd,df->bsf", x, p["wv"].astype(dt)), cfg.n_kv_heads, cfg.head_dim)
-    q = apply_rope(q, rows, cfg.rope_theta)
-    k_new = apply_rope(k_new, rows, cfg.rope_theta)
+    with jax.named_scope(ATTN_PROJ):
+        q = _split_heads(jnp.einsum("bsd,df->bsf", x, p["wq"].astype(dt)), cfg.n_heads, cfg.head_dim)
+        k_new = _split_heads(jnp.einsum("bsd,df->bsf", x, p["wk"].astype(dt)), cfg.n_kv_heads, cfg.head_dim)
+        v_new = _split_heads(jnp.einsum("bsd,df->bsf", x, p["wv"].astype(dt)), cfg.n_kv_heads, cfg.head_dim)
+        q = apply_rope(q, rows, cfg.rope_theta)
+        k_new = apply_rope(k_new, rows, cfg.rope_theta)
 
-    dest = paged_write_indices(rows, n_valid, tables, bs, nb)
     kv, dh = cfg.n_kv_heads, cfg.head_dim
-    flat_k = arena_k.reshape(nb * bs, kv, dh)
-    flat_v = arena_v.reshape(nb * bs, kv, dh)
     clip_tok = None
-    if scales is not None:
-        k_scale, v_scale = scales
-        if probe:
-            flat_k, k_scale, kclip = paged_quant_write(
-                flat_k, k_scale, k_new.reshape(b * c_len, kv, dh), dest, bs,
-                return_clip=True)
-            flat_v, v_scale, vclip = paged_quant_write(
-                flat_v, v_scale, v_new.reshape(b * c_len, kv, dh), dest, bs,
-                return_clip=True)
-            clip_tok = kclip | vclip
+    with jax.named_scope(KV_WRITE):
+        dest = paged_write_indices(rows, n_valid, tables, bs, nb)
+        flat_k = arena_k.reshape(nb * bs, kv, dh)
+        flat_v = arena_v.reshape(nb * bs, kv, dh)
+        if scales is not None:
+            k_scale, v_scale = scales
+            if probe:
+                flat_k, k_scale, kclip = paged_quant_write(
+                    flat_k, k_scale, k_new.reshape(b * c_len, kv, dh), dest, bs,
+                    return_clip=True)
+                flat_v, v_scale, vclip = paged_quant_write(
+                    flat_v, v_scale, v_new.reshape(b * c_len, kv, dh), dest, bs,
+                    return_clip=True)
+                clip_tok = kclip | vclip
+            else:
+                flat_k, k_scale = paged_quant_write(
+                    flat_k, k_scale, k_new.reshape(b * c_len, kv, dh), dest, bs)
+                flat_v, v_scale = paged_quant_write(
+                    flat_v, v_scale, v_new.reshape(b * c_len, kv, dh), dest, bs)
+            arenas = (flat_k.reshape(arena_k.shape), flat_v.reshape(arena_v.shape),
+                      k_scale, v_scale)
+            rd_scales = (k_scale, v_scale)
         else:
-            flat_k, k_scale = paged_quant_write(
-                flat_k, k_scale, k_new.reshape(b * c_len, kv, dh), dest, bs)
-            flat_v, v_scale = paged_quant_write(
-                flat_v, v_scale, v_new.reshape(b * c_len, kv, dh), dest, bs)
-        arenas = (flat_k.reshape(arena_k.shape), flat_v.reshape(arena_v.shape),
-                  k_scale, v_scale)
-        rd_scales = (k_scale, v_scale)
-    else:
-        flat_k = flat_k.at[dest].set(k_new.reshape(b * c_len, kv, dh).astype(flat_k.dtype), mode="drop")
-        flat_v = flat_v.at[dest].set(v_new.reshape(b * c_len, kv, dh).astype(flat_v.dtype), mode="drop")
-        arenas = (flat_k.reshape(arena_k.shape), flat_v.reshape(arena_v.shape))
-        rd_scales = None
+            flat_k = flat_k.at[dest].set(k_new.reshape(b * c_len, kv, dh).astype(flat_k.dtype), mode="drop")
+            flat_v = flat_v.at[dest].set(v_new.reshape(b * c_len, kv, dh).astype(flat_v.dtype), mode="drop")
+            arenas = (flat_k.reshape(arena_k.shape), flat_v.reshape(arena_v.shape))
+            rd_scales = None
 
     path = paged_read_path(cfg)
     group = cfg.n_heads // kv
@@ -550,28 +558,32 @@ def attn_paged_chunk(cfg: ModelConfig, p: dict, arena_k, arena_v, x, positions,
         # gathered stream; same GN datapath, tiled.
         # Chunked queries ride the same kernel (causal intra-chunk mask);
         # int8 arenas dequantize in-kernel, per block, after the DMA.
+        # The GN softmax sits inside the kernel: its time is paged-read time.
         from repro.kernels.gn_paged_attention.ops import gn_paged_attention_chunk
 
-        out = gn_paged_attention_chunk(
-            q,
-            flat_k.reshape(nb, bs, kv, dh),
-            flat_v.reshape(nb, bs, kv, dh),
-            tables,
-            positions,
-            n_valid,
-            interpret=cfg.pallas_interpret,
-            scales=rd_scales,
-        ).reshape(b, c_len, cfg.q_features)
+        with jax.named_scope(PAGED_READ):
+            out = gn_paged_attention_chunk(
+                q,
+                flat_k.reshape(nb, bs, kv, dh),
+                flat_v.reshape(nb, bs, kv, dh),
+                tables,
+                positions,
+                n_valid,
+                interpret=cfg.pallas_interpret,
+                scales=rd_scales,
+            ).reshape(b, c_len, cfg.q_features)
         if probe:
             # reduced probe: probabilities stay in-kernel, so only output
             # finiteness is observable here (see docstring)
-            lane_ok = jnp.arange(c_len)[None, :] < n_valid[:, None]
-            obad = jnp.any(
-                (~jnp.isfinite(out.astype(jnp.float32))) & lane_ok[:, :, None],
-                axis=(1, 2),
-            )
-            probe0 = jnp.where(obad, jnp.inf, 0.0)
-        out = jnp.einsum("bsf,fd->bsd", out.astype(dt), p["wo"].astype(dt))
+            with jax.named_scope(GN_PROBE):
+                lane_ok = jnp.arange(c_len)[None, :] < n_valid[:, None]
+                obad = jnp.any(
+                    (~jnp.isfinite(out.astype(jnp.float32))) & lane_ok[:, :, None],
+                    axis=(1, 2),
+                )
+                probe0 = jnp.where(obad, jnp.inf, 0.0)
+        with jax.named_scope(ATTN_PROJ):
+            out = jnp.einsum("bsf,fd->bsd", out.astype(dt), p["wo"].astype(dt))
         if probe:
             return out, arenas, paged_probe_word(
                 probe0, positions, n_valid, tables, bs, rd_scales, clip_tok)
@@ -579,16 +591,18 @@ def attn_paged_chunk(cfg: ModelConfig, p: dict, arena_k, arena_v, x, positions,
 
     if path == "streamed":
         qg = q.reshape(b, c_len, kv, group, dh)
-        res = _stream_paged_tiles(
-            cfg, qg,
-            flat_k.reshape(nb, bs, kv, dh), flat_v.reshape(nb, bs, kv, dh),
-            tables, rows, scales=rd_scales,
-            probe_nv=n_valid if probe else None,
-        )
+        with jax.named_scope(PAGED_READ):
+            res = _stream_paged_tiles(
+                cfg, qg,
+                flat_k.reshape(nb, bs, kv, dh), flat_v.reshape(nb, bs, kv, dh),
+                tables, rows, scales=rd_scales,
+                probe_nv=n_valid if probe else None,
+            )
         if probe:
             res, probe0 = res
         out = res.reshape(b, c_len, cfg.q_features)
-        out = jnp.einsum("bsf,fd->bsd", out.astype(dt), p["wo"].astype(dt))
+        with jax.named_scope(ATTN_PROJ):
+            out = jnp.einsum("bsf,fd->bsd", out.astype(dt), p["wo"].astype(dt))
         if probe:
             return out, arenas, paged_probe_word(
                 probe0, positions, n_valid, tables, bs, rd_scales, clip_tok)
@@ -600,27 +614,30 @@ def attn_paged_chunk(cfg: ModelConfig, p: dict, arena_k, arena_v, x, positions,
     # unless forced or serving a one-pass-only baseline softmax.  Quantized
     # arenas gather int8 blocks first and dequantize the gathered stream —
     # the oracle is allowed its materialization.
-    k_at = flat_k.reshape(nb, bs, kv, dh)[tables]
-    v_at = flat_v.reshape(nb, bs, kv, dh)[tables]
-    if rd_scales is not None:
-        k_at = k_at.astype(dt) * k_scale[tables].astype(dt)[..., None, None, None]
-        v_at = v_at.astype(dt) * v_scale[tables].astype(dt)[..., None, None, None]
-    k_at = k_at.reshape(b, -1, kv, dh)
-    v_at = v_at.reshape(b, -1, kv, dh)
-    t = k_at.shape[1]  # horizon * bs, tail masked below
+    with jax.named_scope(PAGED_READ):
+        k_at = flat_k.reshape(nb, bs, kv, dh)[tables]
+        v_at = flat_v.reshape(nb, bs, kv, dh)[tables]
+        if rd_scales is not None:
+            k_at = k_at.astype(dt) * k_scale[tables].astype(dt)[..., None, None, None]
+            v_at = v_at.astype(dt) * v_scale[tables].astype(dt)[..., None, None, None]
+        k_at = k_at.reshape(b, -1, kv, dh)
+        v_at = v_at.reshape(b, -1, kv, dh)
+        t = k_at.shape[1]  # horizon * bs, tail masked below
 
-    valid = jnp.arange(t)[None, None, :] <= rows[:, :, None]  # (N, C, T)
-    mask = valid[:, None, None]  # broadcast over (kv, group)
+        valid = jnp.arange(t)[None, None, :] <= rows[:, :, None]  # (N, C, T)
+        mask = valid[:, None, None]  # broadcast over (kv, group)
 
-    qg = q.reshape(b, c_len, kv, group, cfg.head_dim)
-    scores = jnp.einsum("bskgd,btkd->bkgst", qg, k_at) * (cfg.head_dim**-0.5)
-    scores = jnp.where(mask, scores.astype(jnp.float32), NEG_INF)
-    from repro.core import get_softmax
+        qg = q.reshape(b, c_len, kv, group, cfg.head_dim)
+        scores = jnp.einsum("bskgd,btkd->bkgst", qg, k_at) * (cfg.head_dim**-0.5)
+        scores = jnp.where(mask, scores.astype(jnp.float32), NEG_INF)
+        from repro.core import get_softmax
 
-    pmat = get_softmax(cfg.softmax_impl)(scores).astype(v_at.dtype)
-    att = jnp.einsum("bkgst,btkd->bskgd", pmat, v_at)
-    out = jnp.einsum("bsf,fd->bsd", att.reshape(b, c_len, cfg.q_features),
-                     p["wo"].astype(dt))
+        with jax.named_scope(GN_SOFTMAX):
+            pmat = get_softmax(cfg.softmax_impl)(scores).astype(v_at.dtype)
+        att = jnp.einsum("bkgst,btkd->bskgd", pmat, v_at)
+    with jax.named_scope(ATTN_PROJ):
+        out = jnp.einsum("bsf,fd->bsd", att.reshape(b, c_len, cfg.q_features),
+                         p["wo"].astype(dt))
     if probe:
         lane_ok = jnp.arange(c_len)[None, :] < n_valid[:, None]
         probe0 = _probe_sum_residual(pmat, scores, att, valid, lane_ok)

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.core import get_norm
 from repro.configs.base import ModelConfig
+from repro.serve.tracing import GN_NORM, MLP
 
 
 @dataclasses.dataclass(frozen=True)
@@ -77,6 +78,7 @@ def _norm_has_beta(norm_impl: str) -> bool:
     return "ln" in norm_impl  # LayerNorm variants carry beta; RMS variants don't
 
 
+@jax.named_scope(GN_NORM)
 def apply_norm(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
     fn = get_norm(cfg.norm_impl)
     gamma = p["gamma"]
@@ -99,6 +101,7 @@ def mlp_specs(cfg: ModelConfig) -> dict:
     }
 
 
+@jax.named_scope(MLP)
 def apply_mlp(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
     dt = x.dtype
     if cfg.mlp_act == "swiglu":

@@ -48,6 +48,7 @@ from repro.models.layers import (
     stack_specs,
 )
 from repro.parallel.sharding import shard
+from repro.serve.tracing import GN_PROBE, LM_HEAD
 
 
 def _remat(cfg: ModelConfig, fn):
@@ -61,10 +62,11 @@ def _remat(cfg: ModelConfig, fn):
 
 def _lm_head(cfg, params, x):
     x = apply_norm(cfg, params["final_norm"], x)
-    logits = jnp.einsum(
-        "bsd,dv->bsv", x, params["lm_head"]["w"].astype(x.dtype)
-    )
-    return shard(logits, "batch", "seq", "vocab")
+    with jax.named_scope(LM_HEAD):
+        logits = jnp.einsum(
+            "bsd,dv->bsv", x, params["lm_head"]["w"].astype(x.dtype)
+        )
+        return shard(logits, "batch", "seq", "vocab")
 
 
 def _chunk_head(cfg, params, x, n_valid, last_only):
@@ -839,19 +841,21 @@ class Model:
         when the row or its logits contain nonfinite values, and zeroed for
         parked lanes."""
         n = x.shape[0]
-        idx = jnp.maximum(n_valid - 1, 0)[:, None, None]
-        xr = jnp.take_along_axis(x, jnp.broadcast_to(idx, (n, 1, x.shape[-1])), axis=1)
+        with jax.named_scope(LM_HEAD):
+            idx = jnp.maximum(n_valid - 1, 0)[:, None, None]
+            xr = jnp.take_along_axis(x, jnp.broadcast_to(idx, (n, 1, x.shape[-1])), axis=1)
         logits = _lm_head(self.cfg, params, xr)
         if not probe:
             return logits
-        xhat = get_norm(self.cfg.norm_impl)(xr.astype(jnp.float32))
-        sig = jnp.abs(jnp.mean(xhat * xhat, axis=-1) - 1.0)[:, 0]
-        bad = jnp.any(~jnp.isfinite(logits.astype(jnp.float32)),
-                      axis=(1, 2)) | jnp.any(~jnp.isfinite(xr.astype(jnp.float32)),
-                                             axis=(1, 2))
-        head = jnp.where(n_valid > 0,
-                         jnp.where(bad, jnp.inf, sig),
-                         jnp.zeros_like(sig))
+        with jax.named_scope(GN_PROBE):
+            xhat = get_norm(self.cfg.norm_impl)(xr.astype(jnp.float32))
+            sig = jnp.abs(jnp.mean(xhat * xhat, axis=-1) - 1.0)[:, 0]
+            bad = jnp.any(~jnp.isfinite(logits.astype(jnp.float32)),
+                          axis=(1, 2)) | jnp.any(~jnp.isfinite(xr.astype(jnp.float32)),
+                                                 axis=(1, 2))
+            head = jnp.where(n_valid > 0,
+                             jnp.where(bad, jnp.inf, sig),
+                             jnp.zeros_like(sig))
         return logits, head
 
     def _encdec_paged(self, params, cache, x, positions, n_valid, tables,

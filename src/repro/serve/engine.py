@@ -67,6 +67,7 @@ from repro.serve.scheduler import (
     Completion, FCFSScheduler, PriorityScheduler, Request, pad_to_grid,
 )
 from repro.serve.prefix_cache import PrefixCache
+from repro.serve import tracing
 
 # --- GN sentinel thresholds (docs/serving.md §Fault tolerance) -------------
 # Σp residual: the paper's analytic bound for a t-term GN softmax sum is
@@ -546,6 +547,7 @@ class ContinuousEngine:
             jax.jit(model.encode_cross_kv)
             if model.cfg.family == "encdec" else None
         )
+        tracing.trace_gc()
         self.reset(scheduler)
 
     def _put(self, x, sharding):
@@ -634,6 +636,8 @@ class ContinuousEngine:
         self._table_repairs = 0
         self._fault_retries: dict[int, int] = {}
         self._clip_streak = np.zeros(self.num_slots, np.int32)
+        # cumulative wall seconds of each host phase of step()
+        self._phase_s = dict.fromkeys(tracing.PHASES, 0.0)
         self.scheduler = scheduler or self._make_scheduler()
 
     def _make_scheduler(self) -> FCFSScheduler:
@@ -1368,14 +1372,18 @@ class ContinuousEngine:
     # ----------------------------------------------------------- main loop --
     def step(self) -> bool:
         """One engine tick.  Returns False once fully drained (no active
-        slot, nothing queued)."""
+        slot, nothing queued).  Each host phase is a profiler span and adds
+        its wall time to ``metrics()["host_phase_s"]`` (serve/tracing.py)."""
+        phase = functools.partial(tracing.phase, seconds=self._phase_s)
         if self.paged and self.sentinels:
             # block-table redundancy check BEFORE the mirror is pushed to
             # device: the host chain (_slot_blocks) is authoritative, the
             # flat table row is derived — a scribbled entry is repaired in
             # place and the scribble never reaches a device gather.
-            self._check_tables()
-        self._admit()
+            with phase("engine.check_tables"):
+                self._check_tables()
+        with phase("engine.admit"):
+            self._admit()
         live = [s for s, st in enumerate(self._slots) if st is not None]
         if not live:
             if self.scheduler.has_pending():
@@ -1395,114 +1403,134 @@ class ContinuousEngine:
 
         prefills = [s for s in live if self._slots[s].phase == "prefilling"]
         decoders = [s for s in live if self._slots[s].phase == "decoding"]
-        if self._lanes_dirty:  # residency changed: refresh device mirrors
-            self._active_dev = self._put(
-                jnp.asarray(np.array([st is not None for st in self._slots])),
-                self._sh_slot,
-            )
-            self._temps_dev = self._put(jnp.asarray(self._temps), self._sh_slot)
-            self._pos_dev = self._put(jnp.asarray(self.pool.positions), self._sh_slot)
-            self._lanes_dirty = False
-
         takes: dict[int, int] = {}
         for s in prefills:
             st = self._slots[s]
             takes[s] = min(self.chunk, st.prefill_len - st.written)
         paged_args = ()
         if self.paged:
-            # allocate blocks for the positions this tick will write, then
-            # refresh the device table mirror only if residency grew
-            for s in live:
-                self.pool.ensure(s, int(self.pool.positions[s]) + takes.get(s, 1))
-                if self.prefix is not None:
-                    # COW assertion: the block this tick's first write lands
-                    # in must be privately owned (attach-time forking makes
-                    # shared-block writes impossible by construction)
-                    self.pool.write_barrier(s, int(self.pool.positions[s]))
-            if self.pool.tables_dirty:
-                self._tables_dev = self._put(
-                    jnp.asarray(self.pool.tables), self._sh_row
-                )
-                self._tables_sliced.clear()
-                self.pool.tables_dirty = False
-            # Horizon bucketing: slice the traced tables to the smallest
-            # grid bucket covering the live block horizon, so the paged
-            # reads (streamed tiles / kernel grid) touch only live context.
-            # A new bucket is a new tick shape -> one extra compilation,
-            # bounded by len(horizon_bucket_grid) per step kind.
-            horizon = self.pool.active_horizon_blocks()
-            bucket = next(b for b in self.horizon_bucket_grid if b >= horizon)
-            self._buckets_seen["fused" if prefills else "decode"].add(bucket)
-            self.horizon_log.append((horizon, bucket))
-            self._attended_tokens += bucket * self.pool.block_size
-            sliced = self._tables_sliced.get(bucket)
-            if sliced is None:
-                sliced = self._tables_sliced[bucket] = self._tables_dev[:, :bucket]
-            paged_args = (sliced,)
+            with phase("engine.pool_ensure"):
+                # allocate blocks for the positions this tick will write,
+                # then refresh the device table mirror only if residency grew
+                for s in live:
+                    self.pool.ensure(s, int(self.pool.positions[s]) + takes.get(s, 1))
+                    if self.prefix is not None:
+                        # COW assertion: the block this tick's first write
+                        # lands in must be privately owned (attach-time
+                        # forking makes shared-block writes impossible by
+                        # construction)
+                        self.pool.write_barrier(s, int(self.pool.positions[s]))
+                if self.pool.tables_dirty:
+                    self._tables_dev = self._put(
+                        jnp.asarray(self.pool.tables), self._sh_row
+                    )
+                    self._tables_sliced.clear()
+                    self.pool.tables_dirty = False
+                # Horizon bucketing: slice the traced tables to the smallest
+                # grid bucket covering the live block horizon, so the paged
+                # reads (streamed tiles / kernel grid) touch only live
+                # context.  A new bucket is a new tick shape -> one extra
+                # compilation, bounded by len(horizon_bucket_grid) per step
+                # kind.
+                horizon = self.pool.active_horizon_blocks()
+                bucket = next(b for b in self.horizon_bucket_grid if b >= horizon)
+                self._buckets_seen["fused" if prefills else "decode"].add(bucket)
+                self.horizon_log.append((horizon, bucket))
+                self._attended_tokens += bucket * self.pool.block_size
+                sliced = self._tables_sliced.get(bucket)
+                if sliced is None:
+                    sliced = self._tables_sliced[bucket] = self._tables_dev[:, :bucket]
+                paged_args = (sliced,)
         # the logits this tick's decoders choose from, fetched before the
         # tick donates them
         held = (np.asarray(self._last_logits)
                 if self.record_logits and decoders else None)
-        if prefills:
-            chunk_toks = np.zeros((self.num_slots, self.chunk), np.int32)
-            n_valid = np.ones(self.num_slots, np.int32)
-            is_pref = np.zeros(self.num_slots, bool)
-            for s in prefills:
-                st = self._slots[s]
-                chunk_toks[s] = st.padded[st.written : st.written + self.chunk]
-                n_valid[s] = takes[s]
-                is_pref[s] = True
-            # Explicit uploads: every tick operand is a committed device
-            # array before dispatch, so the transfer guard below can
-            # disallow *implicit* host->device transfers — an accidental
-            # numpy arg (a silent per-tick upload) fails loudly instead of
-            # slowly.  ``repro.analysis`` audits the same invariant
-            # statically (A-TRANSFER).
-            chunk_dev = self._put(jnp.asarray(chunk_toks), self._sh_row)
-            nv_dev = self._put(jnp.asarray(n_valid), self._sh_slot)
-            pref_dev = self._put(jnp.asarray(is_pref), self._sh_slot)
-            with jax.transfer_guard_host_to_device("disallow"):
-                self._guarded_ticks += 1
-                outs = self._fused(
-                    self.params, self.pool.cache, self._last_logits, chunk_dev,
-                    self._pos_dev, nv_dev, pref_dev, self._active_dev,
-                    self._temps_dev, self._key, *paged_args,
+        with phase("engine.upload"):
+            if self._lanes_dirty:  # residency changed: refresh device mirrors
+                self._active_dev = self._put(
+                    jnp.asarray(np.array([st is not None for st in self._slots])),
+                    self._sh_slot,
                 )
-            # rebind the donated operands immediately after the call that
-            # invalidated them, per branch — never across the if/else join
-            nxt, self._last_logits, self.pool.cache, self._pos_dev, self._key = (
-                outs[:5])
+                self._temps_dev = self._put(jnp.asarray(self._temps), self._sh_slot)
+                self._pos_dev = self._put(jnp.asarray(self.pool.positions), self._sh_slot)
+                self._lanes_dirty = False
+            if prefills:
+                chunk_toks = np.zeros((self.num_slots, self.chunk), np.int32)
+                n_valid = np.ones(self.num_slots, np.int32)
+                is_pref = np.zeros(self.num_slots, bool)
+                for s in prefills:
+                    st = self._slots[s]
+                    chunk_toks[s] = st.padded[st.written : st.written + self.chunk]
+                    n_valid[s] = takes[s]
+                    is_pref[s] = True
+                # Explicit uploads: every tick operand is a committed device
+                # array before dispatch, so the transfer guard below can
+                # disallow *implicit* host->device transfers — an accidental
+                # numpy arg (a silent per-tick upload) fails loudly instead
+                # of slowly.  ``repro.analysis`` audits the same invariant
+                # statically (A-TRANSFER).
+                chunk_dev = self._put(jnp.asarray(chunk_toks), self._sh_row)
+                nv_dev = self._put(jnp.asarray(n_valid), self._sh_slot)
+                pref_dev = self._put(jnp.asarray(is_pref), self._sh_slot)
+        if prefills:
+            with phase("engine.dispatch"):
+                with jax.transfer_guard_host_to_device("disallow"):
+                    self._guarded_ticks += 1
+                    outs = self._fused(
+                        self.params, self.pool.cache, self._last_logits, chunk_dev,
+                        self._pos_dev, nv_dev, pref_dev, self._active_dev,
+                        self._temps_dev, self._key, *paged_args,
+                    )
+                # rebind the donated operands immediately after the call
+                # that invalidated them, per branch — never across the
+                # if/else join
+                nxt, self._last_logits, self.pool.cache, self._pos_dev, self._key = (
+                    outs[:5])
             self._fused_ticks += 1
         else:  # steady state: every live slot decodes -> the (N, 1) step
-            with jax.transfer_guard_host_to_device("disallow"):
-                self._guarded_ticks += 1
-                outs = self._decode(
-                    self.params, self.pool.cache, self._last_logits,
-                    self._pos_dev, self._active_dev, self._temps_dev, self._key,
-                    *paged_args,
-                )
-            nxt, self._last_logits, self.pool.cache, self._pos_dev, self._key = (
-                outs[:5])
-        if self.sentinels:
-            # one fetch for token + health: the health word rides the tick's
-            # existing device->host download, no extra transfer
-            toks, health = jax.device_get((nxt, outs[5]))
-        else:
-            toks, health = jax.device_get(nxt), None
-        self.pool.advance({s: takes.get(s, 1) for s in live})
-        self._active_steps += len(live)
-        self._prefill_lane_steps += len(prefills)
-        self._decode_steps += 1
-        self._generated += len(decoders)
-        self.phase_log.append((len(prefills), len(decoders)))
+            with phase("engine.dispatch"):
+                with jax.transfer_guard_host_to_device("disallow"):
+                    self._guarded_ticks += 1
+                    outs = self._decode(
+                        self.params, self.pool.cache, self._last_logits,
+                        self._pos_dev, self._active_dev, self._temps_dev, self._key,
+                        *paged_args,
+                    )
+                nxt, self._last_logits, self.pool.cache, self._pos_dev, self._key = (
+                    outs[:5])
+        with phase("engine.device_get"):
+            if self.sentinels:
+                # one fetch for token + health: the health word rides the
+                # tick's existing device->host download, no extra transfer
+                toks, health = jax.device_get((nxt, outs[5]))
+            else:
+                toks, health = jax.device_get(nxt), None
+        with phase("engine.commit"):
+            self.pool.advance({s: takes.get(s, 1) for s in live})
+            self._active_steps += len(live)
+            self._prefill_lane_steps += len(prefills)
+            self._decode_steps += 1
+            self._generated += len(decoders)
+            self.phase_log.append((len(prefills), len(decoders)))
 
         if health is not None:
             # sentinel scan runs BEFORE token append: a violating slot's
             # tick output is garbage, so its token must never land in
             # st.generated — the slot is evicted (recompute resume) or
-            # failed here, and the loops below skip it (st is None).
-            self._sentinel_scan(health, live)
+            # failed here, and the loops below skip it (st is None).  It
+            # reads the advanced positions, so the commit phase is split
+            # around it.
+            with phase("engine.sentinel_scan"):
+                self._sentinel_scan(health, live)
 
+        with phase("engine.commit"):
+            self._commit_tokens(prefills, decoders, takes, toks, held)
+        self.step_count += 1
+        return True
+
+    def _commit_tokens(self, prefills, decoders, takes, toks, held) -> None:
+        """Land the tick's prompt chunks and sampled tokens in their slots
+        and finish the requests that reached a stop."""
         for slot in prefills:
             st = self._slots[slot]
             if st is None:  # fault-evicted this tick
@@ -1531,8 +1559,6 @@ class ContinuousEngine:
                 reason = "length"
             if reason:
                 self._finish(slot, reason)
-        self.step_count += 1
-        return True
 
     def run(self, requests: Sequence[Request]) -> list[Completion]:
         """Serve a workload to completion; returns completions in finish
@@ -2098,6 +2124,9 @@ class ContinuousEngine:
             "per_device_slots": self.num_slots // self.num_devices,
             "shard_balance": self.shard_balance,
             "device_admits": [int(n) for n in self._device_admits],
+            # wall seconds per host phase of step() since the last reset
+            # (serve/tracing.py PHASES)
+            "host_phase_s": dict(self._phase_s),
         }
         if self.paged:
             out.update(

@@ -87,3 +87,38 @@ def test_gn_paged_attention_chunk_compiles(one_chip, kv_dtype, chunk):
         scales = (scale, scale)
     _assert_kernel_compiles(gn_paged_attention_chunk, q, arena, arena, tables,
                             per_seq, per_seq, scales=scales)
+
+
+def test_paged_layer_scopes_survive_the_tpu_compiler(one_chip):
+    """The v5e compiler's optimized HLO of one paged layer step (streamed
+    read, sentinels on) still names every layer scope in the metadata of
+    its fusions: the device trace reads each operation's scopes from it."""
+    import re
+
+    from repro.configs.registry import get_config
+    from repro.models.attention import attn_paged_chunk
+    from repro.models.layers import apply_mlp, apply_norm, spec_struct
+    from repro.models.transformer import make_model
+    from repro.serve import tracing
+
+    cfg = get_config("internlm2-1.8b")
+    assert (cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.d_model) == (H, HKV, DH, D_MODEL)
+    specs = spec_struct(make_model(cfg)._block_specs())
+    lp = jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip), specs)
+
+    def layer(lp, arena_k, arena_v, x, positions, n_valid, tables):
+        h = apply_norm(cfg, lp["ln1"], x)
+        y, arenas, health = attn_paged_chunk(cfg, lp["mixer"], arena_k, arena_v, h,
+                                             positions, n_valid, tables, probe=True)
+        x = x + y
+        return x + apply_mlp(cfg, lp["mlp"], apply_norm(cfg, lp["ln2"], x)), arenas, health
+
+    arena = _sds((NUM_BLOCKS, BLOCK, HKV, DH), jnp.bfloat16, one_chip)
+    x = _sds((N_SEQS, 16, D_MODEL), jnp.bfloat16, one_chip)
+    tables = _sds((N_SEQS, MAX_BT), jnp.int32, one_chip)
+    per_seq = _sds((N_SEQS,), jnp.int32, one_chip)
+    text = jax.jit(layer).lower(lp, arena, arena, x, per_seq, per_seq, tables).compile().as_text()
+    fused = re.findall(r"= \S+ fusion\(.*?op_name=\"([^\"]*)\"", text)
+    seen = {c for path in fused for c in path.split("/")}
+    want = set(tracing.SCOPES) - {tracing.LM_HEAD}  # the head is not in a layer
+    assert want <= seen, sorted(want - seen)
