@@ -6,18 +6,21 @@
 One chip: the staggered continuous-batching workload of
 ``python -m repro.launch.serve --arch internlm2-1.8b --continuous`` (8
 requests, prompts of 64/128/192 tokens, 16 or 32 new tokens, greedy) runs
-through ``ContinuousEngine`` as it runs by default (block-paged pool,
-streamed read, GN sentinels on), unreduced, with f32 params from
-PRNGKey(0).  Every request must finish on its length budget with the
-fault counters at 0.  Its greedy tokens must match the static ``generate``
-oracle's up to reduction order, and fed the served tokens the oracle's
-logits must match the served ones (see ``ULPS``).  A second phase serves
-the same workload with ``use_pallas=True``: the paged read is then the
-Pallas kernel, which must be in the compiled tick, keep the sentinels
+through ``ContinuousEngine`` (block-paged pool, GN sentinels on) with the
+streamed read forced, unreduced, with f32 params from PRNGKey(0).  Every
+request must finish on its length budget with the fault counters at 0.
+Its greedy tokens must match the static ``generate`` oracle's up to
+reduction order, and fed the served tokens the oracle's logits must match
+the served ones (see ``ULPS``).  A second phase serves the same workload
+as it runs by default on one TPU: the paged read is the Pallas kernel,
+which must be in the compiled tick, run every tick, keep the sentinels
 quiet and pass the same logit gate.  A third phase serves both reads with
 float32 activations under float32 matmul precision, where the gates are
 tight enough to fail on a stale KV block or a position off by one
-(``chip_diag.py`` plants both).
+(``chip_diag.py`` plants both).  A fourth serves both reads there over int8
+KV arenas: the kernel unpacks four heads from each 32-bit word of an int8
+arena, and its tokens and first-token logits must match the streamed
+read's over the same quantized arenas under the float32 margin gate.
 
 Four chips: the same workload on ``ContinuousEngine(devices=4)``, built as
 ``launch/serve.py --devices 4`` builds it (params made on the replicated
@@ -34,6 +37,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import json
 import sys
 import time
@@ -48,6 +52,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.launch import serve  # noqa: E402
 from repro.launch.compile_cache import use_compile_cache  # noqa: E402
+from repro.models import attention  # noqa: E402
 from repro.models.transformer import make_model  # noqa: E402
 from repro.serve.engine import static_reference  # noqa: E402
 
@@ -215,16 +220,28 @@ def print_compiles(label: str, m: dict, wall: float) -> None:
           f"set-up wall time, compiles included: {wall:.1f} s")
 
 
+def serve_streamed(model, params, args, scfg, **engine_kw):
+    """``serve.serve_continuous`` with the streamed paged read forced."""
+    attention.FORCE_PAGED_READ = "streamed"
+    try:
+        return serve.serve_continuous(model, params, args, scfg,
+                                      record_logits=True, **engine_kw)
+    finally:
+        attention.FORCE_PAGED_READ = None
+
+
+serve_default = functools.partial(serve.serve_continuous, record_logits=True)
+
+
 def one_chip() -> None:
     args = serve.parse_args(SERVE_ARGS)
     cfg, model, params, scfg = serve.build(args)
     print_widths(cfg, params)
 
-    engine, reqs, comps, wall = serve.serve_continuous(
-        model, params, args, scfg, record_logits=True)
+    engine, reqs, comps, wall = serve_streamed(model, params, args, scfg)
     m = check_served(engine, reqs, comps, "streamed")
     check(m["kv_paged"] and m["read_path"] == "streamed",
-          f"default path is not the streamed paged read: {m['read_path']}")
+          f"the forced streamed paged read did not run: {m['read_path']}")
     print_compiles("streamed", m, wall)
     print_memory("streamed")
     served = tokens_by_id(comps)
@@ -239,11 +256,12 @@ def one_chip() -> None:
     forced_gate("oracle", model, params, reqs, comps, scfg)
     del engine
 
-    pallas_model = make_model(dataclasses.replace(cfg, use_pallas=True))
-    engine, _, comps, wall = serve.serve_continuous(
-        pallas_model, params, args, scfg, record_logits=True)
+    engine, _, comps, wall = serve_default(model, params, args, scfg)
     m = check_served(engine, reqs, comps, "pallas")
-    check(m["read_path"] == "pallas", f"read path {m['read_path']}")
+    check(m["read_path"] == "pallas"
+          and m["kernel_read_ticks"] == m["decode_steps"],
+          f"default read on one TPU: {m['read_path']}, "
+          f"{m['kernel_read_ticks']} of {m['decode_steps']} ticks in the kernel")
     print_compiles("pallas", m, wall)
     agree = {}
     for c in sorted(comps, key=lambda c: c.request_id):
@@ -271,17 +289,38 @@ def one_chip() -> None:
                                                return_logits=True)
         for read in ("streamed", "pallas"):
             label = f"f32-{read}"
-            served_model = (oracle if read == "streamed" else
-                            make_model(dataclasses.replace(f32, use_pallas=True)))
-            engine, _, comps, wall = serve.serve_continuous(
-                served_model, params, args, scfg, record_logits=True)
+            serve_fn = serve_streamed if read == "streamed" else serve_default
+            engine, _, comps, wall = serve_fn(oracle, params, args, scfg)
             m = check_served(engine, reqs, comps, label)
-            check(m["read_path"] == read, f"{label}: read path {m['read_path']}")
+            check(m["read_path"] == read, f"{label}: read {m['read_path']}")
             print_compiles(label, m, wall)
             margin_gate(label, reqs, tokens_by_id(comps), ref32,
                         logits_by_id(comps), ref32_logits, "float32")
             forced_gate(label, oracle, params, reqs, comps, scfg)
             del engine
+
+        # int8 KV arenas: both reads dequantize the same per-block int8
+        # values, so the gap is the reads' arithmetic alone
+        served8 = {}
+        for read, serve_fn in (("streamed", serve_streamed),
+                               ("pallas", serve_default)):
+            label = f"f32-int8-{read}"
+            engine, _, comps, wall = serve_fn(oracle, params, args, scfg,
+                                              kv_dtype="int8")
+            m = check_served(engine, reqs, comps, label)
+            ticks = m["decode_steps"] if read == "pallas" else 0
+            check(m["read_path"] == read and m["kv_dtype"] == "int8"
+                  and m["kernel_read_ticks"] == ticks,
+                  f"{label}: read {m['read_path']}, kv {m['kv_dtype']}, "
+                  f"{m['kernel_read_ticks']} of {m['decode_steps']} ticks "
+                  "in the kernel")
+            print_compiles(label, m, wall)
+            served8[read] = comps
+            del engine
+        margin_gate("f32-int8", reqs, tokens_by_id(served8["pallas"]),
+                    tokens_by_id(served8["streamed"]),
+                    logits_by_id(served8["pallas"]),
+                    logits_by_id(served8["streamed"]), "float32")
     print_memory("f32")
 
 

@@ -434,13 +434,13 @@ def audit_entry_point(ep: EntryPoint, where: str, *,
 
 def check_trace_keys(metrics: dict, where: str, *, paged: bool,
                      max_seq: int = 0, block_size: int = 0,
-                     engine_grid=None) -> list[Finding]:
+                     engine_grid=None, read_path: str = "streamed") -> list[Finding]:
     """Engine-independent core of the A-TRACEKEY audit (fixture-drivable):
     derive the grid from config, compare it to what the engine/metrics
     carry, and pin the CountingJit totals to the seen-key counts."""
     findings: list[Finding] = []
     if paged:
-        derived = tracekeys.horizon_bucket_grid(max_seq, block_size)
+        derived = tracekeys.horizon_bucket_grid(max_seq, block_size, read_path)
         for label, grid in (("engine", engine_grid),
                             ("metrics", metrics.get("horizon_bucket_grid"))):
             if grid is not None and list(grid) != derived:
@@ -492,6 +492,7 @@ def audit_trace_keys(engine, metrics: dict, where: str) -> list[Finding]:
         max_seq=engine.max_seq,
         block_size=engine.pool.block_size if engine.paged else 0,
         engine_grid=engine.horizon_bucket_grid if engine.paged else None,
+        read_path=engine.read_path,
     )
 
 

@@ -16,17 +16,22 @@ from typing import Iterable, Optional
 STEP_KINDS = ("fused", "decode")
 
 
-def horizon_bucket_grid(max_seq: int, block_size: int) -> list[int]:
-    """Power-of-two horizon buckets for a paged pool.
+def horizon_bucket_grid(max_seq: int, block_size: int,
+                        read_path: str = "streamed") -> list[int]:
+    """Horizon buckets for a paged pool, as ``ContinuousEngine.__init__``
+    builds them.
 
-    Mirrors ``ContinuousEngine.__init__``: buckets double from 1 up to the
-    per-slot block capacity, which is always the final bucket (so the
-    full-horizon read is representable even when capacity is not a power
-    of two).
+    Buckets double from 1 up to the per-slot block capacity, which is
+    always the final bucket (so the full-horizon read is representable even
+    when capacity is not a power of two).  The Pallas read (``read_path``
+    'pallas') skips each slot's blocks past its context whatever the
+    table's width, so its grid is that final bucket alone.
     """
     if max_seq <= 0 or block_size <= 0:
         raise ValueError(f"max_seq={max_seq}, block_size={block_size} must be positive")
     max_blocks_per_slot = -(-max_seq // block_size)
+    if read_path == "pallas":
+        return [max_blocks_per_slot]
     grid: list[int] = []
     b = 1
     while b < max_blocks_per_slot:

@@ -16,16 +16,29 @@ from repro.core.luts import SoftmaxLUTConfig
 
 
 def lut_lookup(idx: jax.Array, values: np.ndarray) -> jax.Array:
-    """ROM lookup ``values[idx]`` as a chain of selects over the entries.
+    """ROM lookup ``values[idx]`` as a tree of selects over the entries.
 
     The entries are trace-time constants, so the result is bit-equal to the
-    table; indices outside it read 0.  Selects lower on any tile shape (a
-    one-hot matmul needs an (r*c, 1) reshape that Mosaic refuses).
+    table; indices outside it read 0.  Like a ROM's output mux, bit b of the
+    index picks between the halves of each 2^(b+1)-entry subtable: n - 1
+    selects and one bit test per level, where comparing the index with each
+    entry in turn takes n compares and n selects.  Selects lower on any tile
+    shape (a one-hot matmul needs an (r*c, 1) reshape that Mosaic refuses).
     """
-    out = jnp.zeros(idx.shape, jnp.float32)
-    for j, v in enumerate(values):
-        out = jnp.where(idx == j, jnp.float32(v), out)
-    return out
+    n = len(values)
+    depth = max(1, (n - 1).bit_length())
+    table = [np.float32(v) for v in values] + [np.float32(0)] * ((1 << depth) - n)
+    bit = [((idx >> b) & 1) == 1 for b in range(depth)]
+
+    def pick(lo: int, level: int):  # entries [lo, lo + 2^level)
+        if level == 0:
+            return table[lo]
+        half = 1 << (level - 1)
+        return jnp.where(bit[level - 1], pick(lo + half, level - 1),
+                         pick(lo, level - 1))
+
+    out = jnp.broadcast_to(jnp.asarray(pick(0, depth), jnp.float32), idx.shape)
+    return jnp.where((idx >= 0) & (idx < n), out, jnp.float32(0))
 
 
 def factorized_exp(delta: jax.Array, cfg: SoftmaxLUTConfig) -> jax.Array:

@@ -1,9 +1,14 @@
-"""Jit'd public wrappers for paged GN attention (padding + GQA).
+"""Jit'd public wrappers for paged GN attention (query layout + GQA).
 
 Layout contract with the serving pool: the arena arrives in the pool's
-(num_blocks, block_size, KV, dh) layout; these wrappers transpose it to the
-kernel's head-major block layout and lane-pad the head dim, pad the query
-chunk to the 8-row sublane grid, and trim everything back off the output.
+(num_blocks, block_size, KV, dh) layout and reaches the kernel as
+(num_blocks, block_size * KV, dh), which is a reshape that moves no bytes.
+The head dim is lane-padded only where it is not a multiple of 128, which
+copies the arena; the serving dispatch (``attention.paged_read_path``)
+sends such configs to the streamed read, so only a read asked for
+explicitly pads.  Queries, which are small, are regrouped per KV head in
+f32, so the kernel's 8-row slice of a decoder's rows is a whole tile (a
+packed dtype's tile has 16 or 32 rows); the output is regrouped back.
 
 ``gn_paged_attention_chunk`` is the fused serving tick's entry point: a
 (N, C, H, D) query chunk per sequence, causal within the chunk, the prior
@@ -29,7 +34,8 @@ def _round_up(x: int, m: int) -> int:
 
 
 @functools.partial(
-    jax.jit, static_argnames=("cfg", "sm_scale", "interpret")
+    jax.jit,
+    static_argnames=("cfg", "sm_scale", "interpret", "with_stats"),
 )
 def gn_paged_attention_chunk(
     q: jax.Array,  # (N, C, H, D) one query chunk per sequence
@@ -43,56 +49,63 @@ def gn_paged_attention_chunk(
     sm_scale: float | None = None,
     interpret: bool = False,
     scales: tuple[jax.Array, jax.Array] | None = None,  # ((nb,), (nb,)) f32
-) -> jax.Array:
+    with_stats: bool = False,
+):
     """Chunked-query paged read.  Row i of sequence n attends the logical
     stream [0, starts[n] + i] (causal intra-chunk), bounded by the post-write
-    context starts + n_valid.  Returns (N, C, H, D).
+    context starts + n_valid.  Returns (N, C, H, D) in q's dtype, which is
+    also the MXU operand dtype.
 
     ``scales`` marks the arenas as int8: per-physical-block dequant scales
-    for k and v, applied inside the kernel after each block tile's DMA."""
+    for k and v, applied inside the kernel after each block tile's DMA.
+
+    ``with_stats`` also returns the kernel's per-row sentinel statistics,
+    (N, C, H) f32: the row's normalization residual, +inf where its live
+    scores or its output are nonfinite (see kernel.py)."""
     n, c, h, d = q.shape
     nb, bs, hkv, _ = k_arena.shape
+    if h % hkv:
+        raise ValueError(f"q heads {h} not a multiple of kv heads {hkv}")
+    group = h // hkv
     if sm_scale is None:
         sm_scale = d**-0.5  # scale uses the TRUE head dim, not the padded one
     k_scale = v_scale = None
     if scales is not None:
-        k_scale = scales[0].astype(jnp.float32)
-        v_scale = scales[1].astype(jnp.float32)
+        k_scale, v_scale = scales
 
     d_p = _round_up(d, LANE)
-    # quantized (int8) arenas need the (32, 128) minimum TPU tile in the
-    # sublane dim; fp arenas keep the 8-row grid
-    sub = SUBLANE
-    if scales is not None:
-        sub = 32
-    bs_p = _round_up(bs, sub)
-    c_p = _round_up(c, SUBLANE)
+    rows = _round_up(c * group, SUBLANE)
+    qs = q.astype(jnp.float32).reshape(n, c, hkv, group, d)
+    qs = qs.transpose(0, 2, 1, 3, 4).reshape(n, hkv, c * group, d)
+    qs = jnp.pad(qs, ((0, 0), (0, 0), (0, rows - c * group), (0, d_p - d)))
+    if d_p != d:
+        pad = ((0, 0), (0, 0), (0, 0), (0, d_p - d))
+        k_arena, v_arena = jnp.pad(k_arena, pad), jnp.pad(v_arena, pad)
 
-    qp = jnp.pad(
-        q.transpose(0, 2, 1, 3), ((0, 0), (0, 0), (0, c_p - c), (0, d_p - d))
-    )  # (N, H, c_p, d_p)
-    kp = jnp.pad(
-        k_arena.transpose(0, 2, 1, 3), ((0, 0), (0, 0), (0, bs_p - bs), (0, d_p - d))
-    )
-    vp = jnp.pad(
-        v_arena.transpose(0, 2, 1, 3), ((0, 0), (0, 0), (0, bs_p - bs), (0, d_p - d))
-    )
-
-    out = gn_paged_attention_pallas(
-        qp,
-        kp,
-        vp,
+    out, stats = gn_paged_attention_pallas(
+        qs,
+        k_arena.reshape(nb, bs * hkv, d_p),
+        v_arena.reshape(nb, bs * hkv, d_p),
         tables.astype(jnp.int32),
         starts.astype(jnp.int32),
         (starts + n_valid).astype(jnp.int32),
         cfg=cfg,
         sm_scale=float(sm_scale),
         block_size=bs,
+        group=group,
+        compute_dtype=q.dtype,
+        out_dtype=q.dtype,
         interpret=interpret,
         k_scale=k_scale,
         v_scale=v_scale,
     )
-    return out[:, :, :c, :d].transpose(0, 2, 1, 3)
+    out = out[:, :, : c * group, :d].reshape(n, hkv, c, group, d)
+    out = out.transpose(0, 2, 1, 3, 4).reshape(n, c, h, d)
+    if not with_stats:
+        return out
+    # stats[n, c * group + g, kv head] -> (N, C, H) by query head
+    stats = stats[:, : c * group, :hkv].reshape(n, c, group, hkv)
+    return out, stats.transpose(0, 1, 3, 2).reshape(n, c, h)
 
 
 @functools.partial(

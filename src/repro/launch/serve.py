@@ -117,7 +117,8 @@ def serve_continuous(model, params, args, scfg, **engine_kw):
     print("host phases of step(), ms: " + ", ".join(
         f"{name} {s * 1e3:.1f}" for name, s in m["host_phase_s"].items()))
     if m["kv_paged"]:
-        print(f"paged reads: {m['read_path']}; horizon buckets "
+        print(f"paged reads: {m['read_path']} ({m['kernel_read_ticks']} of "
+              f"{m['decode_steps']} ticks through the Pallas kernel); horizon buckets "
               f"{m['horizon_buckets']} of grid {m['horizon_bucket_grid']} "
               f"(mean attended {m['mean_attended_tokens_per_tick']:.1f} "
               "tok/tick)")

@@ -374,15 +374,38 @@ def paged_quant_write(flat_arena, scale, new_vals, dest, block_size: int,
     return out, scale
 
 
-def paged_read_path(cfg: ModelConfig) -> str:
+# the kernel read's layout: head dims in whole lane tiles, and KV heads in
+# whole 32-bit words of the narrowest arena dtype (int8, four to a word)
+KERNEL_LANE = 128
+KERNEL_KV_PACK = 4
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def paged_read_path(cfg: ModelConfig, num_devices: int = 1) -> str:
     """Which paged attention read the serving tick uses for dense KV:
-    'pallas' (TPU kernel, online GN accumulation), 'streamed' (lax.scan
-    over block tiles emitting score tiles — bitwise equal to the gathered
-    read without materializing the K stream), or 'gathered' (full-stream
-    materialization; baselines-only oracle)."""
+    'pallas' (TPU kernel, online GN accumulation, nothing materialized),
+    'streamed' (lax.scan over block tiles emitting score tiles — bitwise
+    equal to the gathered read without materializing the K stream), or
+    'gathered' (full-stream materialization; baselines-only oracle).
+
+    The GN read takes the kernel wherever the tick runs on one TPU device,
+    from what the process can see: the backend, the engine's device count
+    and the KV shape.  A slot mesh of several devices keeps the streamed
+    read (a kernel inside the GSPMD tick would need shard_map), as do the
+    CPU and the exact softmax.  So does a KV shape the kernel cannot read in
+    the pool's layout: a head dim that is not a whole number of 128-lane
+    tiles (the kernel would pad a copy of the arena every layer), or a KV
+    head count that does not fill the 32-bit words of an int8 arena."""
     if FORCE_PAGED_READ is not None:
         return FORCE_PAGED_READ
     if cfg.use_pallas:
+        return "pallas"
+    if (cfg.softmax_impl == "gn" and num_devices == 1 and _on_tpu()
+            and cfg.head_dim % KERNEL_LANE == 0
+            and cfg.n_kv_heads % KERNEL_KV_PACK == 0):
         return "pallas"
     # the online accumulation needs a streaming-stable softmax: GN (snap-to-
     # Δ-grid LUT exp) or the exact float path; one-pass-only baselines fall
@@ -460,7 +483,7 @@ def _stream_paged_tiles(cfg: ModelConfig, qg, arena_k, arena_v, tables, rows,
 
 
 def attn_paged_chunk(cfg: ModelConfig, p: dict, arena_k, arena_v, x, positions,
-                     n_valid, tables, scales=None, probe=False):
+                     n_valid, tables, scales=None, probe=False, read_path=None):
     """Block-paged chunked append-decode, batched over slots.
 
     The slot-monolithic ``attn_decode_chunk`` owns a (max_seq,) slab per
@@ -483,10 +506,12 @@ def attn_paged_chunk(cfg: ModelConfig, p: dict, arena_k, arena_v, x, positions,
     either the weighted sum or the normalizer — Σp = 1 over the same score
     multiset as the slab path, independent of block layout.
 
-    The read itself is dispatched by ``paged_read_path``: the Pallas kernel
-    (TPU; chunked queries included), the streamed block-tile scan (CPU/GPU
-    default — bitwise equal to the gathered read, K stream never
-    materialized), or the gathered oracle (baselines/tests only).
+    The read is ``read_path`` (the engine passes the one its device count
+    selects; None asks ``paged_read_path`` for one device): the Pallas
+    kernel (single-TPU default; chunked queries included), the streamed
+    block-tile scan (CPU and slot-mesh default — bitwise equal to the
+    gathered read, K stream never materialized), or the gathered oracle
+    (baselines/tests only).
 
     ``scales=(k_scale, v_scale)`` ((num_blocks,) f32 each) switches the
     arenas to int8 with per-block dequantization scales: writes quantize
@@ -501,12 +526,11 @@ def attn_paged_chunk(cfg: ModelConfig, p: dict, arena_k, arena_v, x, positions,
 
     ``probe=True`` (a static Python bool — the engine binds it as a closure
     constant, so it adds no trace keys) appends a third return: this
-    layer's (N, 3) GN sentinel health word (see ``paged_probe_word``).  The
-    streamed and gathered reads compute the full Σp-residual/finiteness
-    probe from their materialized score rows; the Pallas kernel keeps its
-    probabilities in-kernel, so its probe is reduced to output finiteness
-    (documented coverage gap: NaN-K laundering is only certified on the
-    streamed/gathered paths — the CPU/GPU default and the CI path).
+    layer's (N, 3) GN sentinel health word (see ``paged_probe_word``).  Every
+    read gives channel 0 the same meaning: the streamed and gathered reads
+    compute it from their materialized score rows, the Pallas kernel from
+    per-row statistics it keeps alongside its online state (its own Σp
+    residual, +inf on a nonfinite live score or output).
     """
     dt = x.dtype
     b, c_len = x.shape[:2]
@@ -550,19 +574,17 @@ def attn_paged_chunk(cfg: ModelConfig, p: dict, arena_k, arena_v, x, positions,
             arenas = (flat_k.reshape(arena_k.shape), flat_v.reshape(arena_v.shape))
             rd_scales = None
 
-    path = paged_read_path(cfg)
+    path = read_path or paged_read_path(cfg)
     group = cfg.n_heads // kv
     if path == "pallas":
-        # single-chip TPU hot path: the Pallas kernel chases the block table
-        # with scalar-prefetched index maps instead of materializing the
-        # gathered stream; same GN datapath, tiled.
-        # Chunked queries ride the same kernel (causal intra-chunk mask);
-        # int8 arenas dequantize in-kernel, per block, after the DMA.
-        # The GN softmax sits inside the kernel: its time is paged-read time.
+        # the Pallas kernel chases the block table with scalar-prefetched
+        # index maps and keeps scores, probabilities and V tiles in VMEM;
+        # int8 arenas dequantize in-kernel, per block, after the DMA.  The
+        # GN softmax sits inside the kernel: its time is paged-read time.
         from repro.kernels.gn_paged_attention.ops import gn_paged_attention_chunk
 
         with jax.named_scope(PAGED_READ):
-            out = gn_paged_attention_chunk(
+            res = gn_paged_attention_chunk(
                 q,
                 flat_k.reshape(nb, bs, kv, dh),
                 flat_v.reshape(nb, bs, kv, dh),
@@ -571,17 +593,17 @@ def attn_paged_chunk(cfg: ModelConfig, p: dict, arena_k, arena_v, x, positions,
                 n_valid,
                 interpret=cfg.pallas_interpret,
                 scales=rd_scales,
-            ).reshape(b, c_len, cfg.q_features)
+                with_stats=probe,
+            )
+        out, stats = res if probe else (res, None)
+        out = out.reshape(b, c_len, cfg.q_features)
         if probe:
-            # reduced probe: probabilities stay in-kernel, so only output
-            # finiteness is observable here (see docstring)
+            # channel 0 from the kernel's per-row statistics (N, C, H):
+            # the worst live lane over every head
             with jax.named_scope(GN_PROBE):
                 lane_ok = jnp.arange(c_len)[None, :] < n_valid[:, None]
-                obad = jnp.any(
-                    (~jnp.isfinite(out.astype(jnp.float32))) & lane_ok[:, :, None],
-                    axis=(1, 2),
-                )
-                probe0 = jnp.where(obad, jnp.inf, 0.0)
+                probe0 = jnp.max(jnp.where(lane_ok[:, :, None], stats, 0.0),
+                                 axis=(1, 2))
         with jax.named_scope(ATTN_PROJ):
             out = jnp.einsum("bsf,fd->bsd", out.astype(dt), p["wo"].astype(dt))
         if probe:

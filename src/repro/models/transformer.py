@@ -638,16 +638,21 @@ class Model:
         )
 
     # ------------------------------------------------- block-paged cache ---
-    @property
-    def paged_read_path(self) -> str:
-        """How the serving tick reads paged KV for this family: 'pallas'
-        (dense TPU kernel), 'streamed' (block-tile scan, the CPU/GPU
-        default) or 'gathered' (full-stream oracle, baselines only).  The
-        engine surfaces this in ``metrics()`` and the bench folds it into
-        the workload hash so trajectories don't mix read paths."""
+    def paged_read_path_on(self, num_devices: int = 1) -> str:
+        """How a serving tick over ``num_devices`` devices reads paged KV
+        for this family: 'pallas' (dense GN kernel, one TPU device),
+        'streamed' (block-tile scan: the CPU, a slot mesh, MLA) or
+        'gathered' (full-stream oracle, baselines only).  The engine
+        surfaces this in ``metrics()`` and the bench folds it into the
+        workload hash so trajectories don't mix read paths."""
         if self.cfg.mla is not None:
             return mla_mod.mla_paged_read_path(self.cfg)
-        return attn.paged_read_path(self.cfg)
+        return attn.paged_read_path(self.cfg, num_devices)
+
+    @property
+    def paged_read_path(self) -> str:
+        """``paged_read_path_on`` one device."""
+        return self.paged_read_path_on(1)
 
     @property
     def supports_paging(self) -> bool:
@@ -745,7 +750,7 @@ class Model:
         return {**pool_cache, **jax.tree.map(upd, extras, request, axes)}
 
     def fused_step_slots_paged(self, params, cache, tokens, positions, n_valid,
-                               tables, sentinel=False):
+                               tables, sentinel=False, read_path=None):
         """Block-paged counterpart of ``fused_step_slots``: every slot
         processes its own C-token chunk at its own write offset, but KV lives
         in shared block arenas addressed through per-slot block tables
@@ -767,7 +772,9 @@ class Model:
         pytree ``{"layers": (L, N, 3) f32, "head": (N,) f32}`` of GN
         sentinel probes (Σp residual / clip fraction / scale sanity per
         layer, σ residual at the head) accumulated on-device — no host
-        transfer and no extra compile keys."""
+        transfer and no extra compile keys.  ``read_path`` (static) names
+        the dense paged read, as ``paged_read_path_on`` chose it; None lets
+        each layer ask for one device."""
         cfg = self.cfg
         dt = jnp.dtype(cfg.dtype)
         x = params["embed"]["tok"].astype(dt)[tokens]  # (N, C, D)
@@ -775,10 +782,10 @@ class Model:
 
         if cfg.family == "vlm":
             return self._vlm_paged(params, cache, x, positions, n_valid,
-                                   tables, sentinel)
+                                   tables, sentinel, read_path)
         if cfg.family == "encdec":
             return self._encdec_paged(params, cache, x, positions, n_valid,
-                                      tables, sentinel)
+                                      tables, sentinel, read_path)
 
         def body(x, scanned):
             lp, lcache = scanned
@@ -803,12 +810,13 @@ class Model:
                         cfg, lp["mixer"], lcache["k"], lcache["v"], h,
                         positions, n_valid, tables,
                         scales=(lcache["k_scale"], lcache["v_scale"]),
-                        probe=sentinel)
+                        probe=sentinel, read_path=read_path)
                     nc = {"k": nk, "v": nv, "k_scale": nks, "v_scale": nvs}
                 else:
                     y, (nk, nv), *pr = attn.attn_paged_chunk(
                         cfg, lp["mixer"], lcache["k"], lcache["v"], h,
-                        positions, n_valid, tables, probe=sentinel)
+                        positions, n_valid, tables, probe=sentinel,
+                        read_path=read_path)
                     nc = {"k": nk, "v": nv}
             x = x + y
             if "mlp" in lp:
@@ -859,7 +867,7 @@ class Model:
         return logits, head
 
     def _encdec_paged(self, params, cache, x, positions, n_valid, tables,
-                      sentinel=False):
+                      sentinel=False, read_path=None):
         cfg = self.cfg
 
         def body(x, scanned):
@@ -870,12 +878,13 @@ class Model:
                     cfg, lp["mixer"], lcache["k"], lcache["v"], h,
                     positions, n_valid, tables,
                     scales=(lcache["k_scale"], lcache["v_scale"]),
-                    probe=sentinel)
+                    probe=sentinel, read_path=read_path)
                 nc = {"k": nk, "v": nv, "k_scale": nks, "v_scale": nvs}
             else:
                 y, (nk, nv), *pr = attn.attn_paged_chunk(
                     cfg, lp["mixer"], lcache["k"], lcache["v"], h,
-                    positions, n_valid, tables, probe=sentinel)
+                    positions, n_valid, tables, probe=sentinel,
+                    read_path=read_path)
                 nc = {"k": nk, "v": nv}
             x = x + y
             hx = apply_norm(cfg, lp["ln_x"], x)
@@ -895,7 +904,7 @@ class Model:
         return self._paged_head(params, x, n_valid), {**cache, "layers": ys}
 
     def _vlm_paged(self, params, cache, x, positions, n_valid, tables,
-                   sentinel=False):
+                   sentinel=False, read_path=None):
         cfg = self.cfg
         g = cfg.n_layers // cfg.cross_attn_every
         layers = self._group_tree(params["layers"], g)
@@ -914,12 +923,13 @@ class Model:
                         cfg, lp["mixer"], lc["k"], lc["v"], h,
                         positions, n_valid, tables,
                         scales=(lc["k_scale"], lc["v_scale"]),
-                        probe=sentinel)
+                        probe=sentinel, read_path=read_path)
                     nc = {"k": nk, "v": nv, "k_scale": nks, "v_scale": nvs}
                 else:
                     y, (nk, nv), *pr = attn.attn_paged_chunk(
                         cfg, lp["mixer"], lc["k"], lc["v"], h,
-                        positions, n_valid, tables, probe=sentinel)
+                        positions, n_valid, tables, probe=sentinel,
+                        read_path=read_path)
                     nc = {"k": nk, "v": nv}
                 x2 = x2 + y
                 h2 = apply_norm(cfg, lp["ln2"], x2)

@@ -58,6 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.analysis.tracekeys import horizon_bucket_grid
 from repro.checkpoint import store as ckpt_store
 from repro.models.attention import SCALE_SANITY_MAX
 from repro.models.transformer import Model
@@ -430,6 +431,10 @@ class ContinuousEngine:
                 f"family {model.cfg.family!r} (sliding_window="
                 f"{model.cfg.sliding_window}) has no pageable KV"
             )
+        # The paged read every tick of this engine runs, fixed by the
+        # backend, the device count and the config (``paged_read_path_on``).
+        self.read_path = (model.paged_read_path_on(self.num_devices)
+                          if self.paged else "slab")
         # Quantized paged KV: int8 arenas + per-block f32 dequant scales
         # carried as cache leaves alongside the arenas.  Paged-only — the
         # slab pool has no per-block scale granularity to hang scales on.
@@ -475,13 +480,12 @@ class ContinuousEngine:
             # i.e. fused <= len(grid) and decode <= len(grid) instead of
             # one tick shape per horizon.  Powers of two up to
             # max_blocks_per_slot, which caps the grid at
-            # ceil(log2(max_bt)) + 1 entries.
-            grid, b = [], 1
-            while b < self.pool.max_blocks_per_slot:
-                grid.append(b)
-                b *= 2
-            grid.append(self.pool.max_blocks_per_slot)
-            self.horizon_bucket_grid: list[int] = grid
+            # ceil(log2(max_bt)) + 1 entries.  The Pallas read's cost
+            # follows each slot's own context (its grid skips the steps
+            # past it), so there the grid is the one full-width bucket:
+            # two tick programs instead of two per bucket.
+            self.horizon_bucket_grid: list[int] = horizon_bucket_grid(
+                self.pool.max_seq, self.pool.block_size, self.read_path)
             # Prefix sharing (opt-in): a per-device radix index over finished
             # prompt prefixes.  Admission attaches fully-matched cached
             # blocks read-only (refcount++), COW-forks a partially-matched
@@ -594,6 +598,7 @@ class ContinuousEngine:
         self._active_steps = 0   # sum over decode steps of active-slot count
         self._decode_steps = 0
         self._fused_ticks = 0    # ticks that carried at least one prefill lane
+        self._kernel_read_ticks = 0  # ticks whose paged read ran the kernel
         self._prefill_lane_steps = 0  # sum over ticks of prefilling slots
         self._generated = 0
         self.phase_log: list[tuple[int, int]] = []  # (prefill, decode) lanes/tick
@@ -756,7 +761,7 @@ class ContinuousEngine:
         nv = jnp.where(active, 1, 0).astype(jnp.int32)
         out = self.model.fused_step_slots_paged(
             params, cache, nxt[:, None], pos, nv, tables,
-            sentinel=self.sentinels,
+            sentinel=self.sentinels, read_path=self.read_path,
         )
         logits, ncache = out[0], out[1]
         new_last = jnp.where(
@@ -784,7 +789,8 @@ class ContinuousEngine:
         nv = jnp.where(active, nv, 0).astype(jnp.int32)
         pos = jnp.where(active, positions, 0)
         out = self.model.fused_step_slots_paged(
-            params, cache, tokens, pos, nv, tables, sentinel=self.sentinels
+            params, cache, tokens, pos, nv, tables, sentinel=self.sentinels,
+            read_path=self.read_path,
         )
         logits, ncache = out[0], out[1]
         new_last = jnp.where(
@@ -1498,6 +1504,7 @@ class ContinuousEngine:
                     )
                 nxt, self._last_logits, self.pool.cache, self._pos_dev, self._key = (
                     outs[:5])
+        self._kernel_read_ticks += self.read_path == "pallas"
         with phase("engine.device_get"):
             if self.sentinels:
                 # one fetch for token + health: the health word rides the
@@ -2137,14 +2144,16 @@ class ContinuousEngine:
                 block_utilization=(
                     self.pool.peak_blocks_in_use / max(1, self.pool.num_blocks)
                 ),
-                # which gather-free read the tick ran (pallas/streamed;
-                # 'gathered' only under a forced/baseline fallback) and the
+                # which gather-free read this engine's ticks take (pallas/
+                # streamed; 'gathered' only under a forced/baseline
+                # fallback), how many ticks ran the Pallas kernel, and the
                 # horizon-bucketing trajectory: the grid, the buckets each
                 # step kind actually traced (compile counters are exactly
-                # one per (kind, bucket) -> the documented upper bound),
-                # and the mean attended stream width per tick — the
-                # quantity that now scales with live tokens, not max_seq
-                read_path=self.model.paged_read_path,
+                # one per (kind, bucket) -> the documented upper bound), and
+                # the mean attended stream width per tick — the quantity
+                # that now scales with live tokens, not max_seq
+                read_path=self.read_path,
+                kernel_read_ticks=self._kernel_read_ticks,
                 kv_dtype=self.pool.kv_dtype,
                 horizon_bucket_grid=list(self.horizon_bucket_grid),
                 horizon_buckets=sorted(
@@ -2175,5 +2184,6 @@ class ContinuousEngine:
                 )
         else:
             out["read_path"] = "slab"
+            out["kernel_read_ticks"] = 0
             out["kv_dtype"] = "fp"
         return out

@@ -57,6 +57,14 @@ def test_horizon_bucket_grid_matches_engine_rule():
     assert tracekeys.horizon_bucket_grid(4, 4) == [1]
 
 
+@pytest.mark.parametrize("read_path,want", [
+    ("streamed", [1, 2, 4, 6]), ("gathered", [1, 2, 4, 6]), ("pallas", [6]),
+])
+def test_horizon_bucket_grid_by_read(read_path, want):
+    # the kernel skips blocks past each slot's context: one full bucket
+    assert tracekeys.horizon_bucket_grid(24, 4, read_path) == want
+
+
 def test_trace_key_space_and_bound():
     keys = tracekeys.trace_key_space(paged=True, max_seq=16, block_size=4)
     assert keys == {(k, b) for k in ("fused", "decode") for b in (1, 2, 4)}

@@ -12,6 +12,7 @@ under several workers.
 """
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -87,6 +88,114 @@ def test_gn_paged_attention_chunk_compiles(one_chip, kv_dtype, chunk):
         scales = (scale, scale)
     _assert_kernel_compiles(gn_paged_attention_chunk, q, arena, arena, tables,
                             per_seq, per_seq, scales=scales)
+
+
+def _relayouts_feeding_kernels(text: str, arena_elems: int):
+    """(number of Pallas calls, arena-sized transposes, copies and pads on
+    the producer chains of their operands) in compiled HLO ``text``.
+
+    A chain is followed back to a parameter, the layer loop's state, or the
+    loop's per-layer slice of the stacked arenas (which every read has); a
+    fusion counts when it holds an arena-sized relayout."""
+    import re
+
+    defs, comps, comp = {}, {}, None
+    for line in text.splitlines():
+        head = re.match(r"\s*(?:ENTRY )?%(\S+) .*\{\s*$", line)
+        if head and " = " not in line.split("{")[0]:
+            comp = head.group(1)
+            comps[comp] = []
+            continue
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.+?) ([\w\-]+)\((.*)", line)
+        if not m:
+            continue
+        name, shape, op, rest = m.groups()
+        size = max((int(np.prod([int(d) for d in dims.split(",") if d]))
+                    for dims in re.findall(r"\w+\[([\d,]*)\]", shape)), default=0)
+        operands = re.findall(r"%([\w.\-]+)", re.split(r"\), \w+=", rest)[0])
+        calls = re.search(r"calls=%([\w.\-]+)", rest)
+        defs[name] = (op, size, operands, calls and calls.group(1), line)
+        comps[comp].append((op, size))
+    relayout = {"copy", "transpose", "pad"}
+    kernels, bad = 0, []
+    for op, _, operands, _, line in defs.values():
+        if op != "custom-call" or "tpu_custom_call" not in line:
+            continue
+        kernels += 1
+        seen, stack = set(), list(operands)
+        while stack:
+            name = stack.pop()
+            if name in seen or name not in defs:
+                continue
+            seen.add(name)
+            op, size, operands, calls, _ = defs[name]
+            if size < arena_elems or op in ("parameter", "get-tuple-element"):
+                continue
+            inner = comps.get(calls, [])
+            if op in relayout or any(o in relayout and s >= arena_elems for o, s in inner):
+                bad.append(name)
+            if not any(o == "dynamic-slice" for o, _ in inner):
+                stack.extend(operands)
+    return kernels, bad
+
+
+def _served_tick_text(sharding, arch, n, chunk, num_blocks, kv_dtype, read_path):
+    """Optimized HLO of the whole serving tick of ``arch`` at full widths
+    (blocks of 64, a 64-block horizon, sentinels on) over ``read_path``."""
+    from repro.configs.registry import get_config
+    from repro.models.transformer import make_model
+
+    block, max_bt = 64, 64
+    model = make_model(get_config(arch))
+    put = lambda tree: jax.tree.map(  # noqa: E731
+        lambda s: _sds(s.shape, s.dtype, sharding), tree)
+    params = put(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = put(model.paged_cache_specs(n, num_blocks, block, max_bt * block,
+                                        kv_dtype=kv_dtype))
+
+    def tick(params, cache, tokens, positions, n_valid, tables):
+        return model.fused_step_slots_paged(params, cache, tokens, positions,
+                                            n_valid, tables, sentinel=True,
+                                            read_path=read_path)
+
+    per_seq = _sds((n,), jnp.int32, sharding)
+    return jax.jit(tick, donate_argnums=(1,)).lower(
+        params, cache, _sds((n, chunk), jnp.int32, sharding), per_seq, per_seq,
+        _sds((n, max_bt), jnp.int32, sharding)).compile().as_text()
+
+
+@pytest.mark.parametrize("kv_dtype", ["fp", "int8"])
+@pytest.mark.parametrize("n,chunk,num_blocks", [(8, 64, 400), (16, 1, 320)],
+                         ids=["docqa-fused", "chat-decode"])
+def test_served_paged_read_compiles_without_arena_copies(one_chip, n, chunk,
+                                                         num_blocks, kv_dtype):
+    """The whole serving tick at the benchmark cells' shapes (internlm2-1.8b,
+    24 layers, blocks of 64, a 64-block horizon, sentinels on) with the
+    kernel read: every layer's read is the Pallas kernel, and no arena-sized
+    transpose, pad or copy feeds it: it reads the pool's arena in place."""
+    text = _served_tick_text(one_chip, "internlm2-1.8b", n, chunk, num_blocks,
+                             kv_dtype, "pallas")
+    kernels, relayouts = _relayouts_feeding_kernels(text, num_blocks * 64 * HKV * DH)
+    assert kernels == 1  # the layer loop's body
+    assert not relayouts, relayouts
+
+
+@pytest.mark.parametrize("kv_dtype", ["fp", "int8"])
+def test_head_dim_64_keeps_the_streamed_read_on_one_tpu(one_chip, monkeypatch,
+                                                        kv_dtype):
+    """stablelm-1.6b (32 KV heads of dim 64) on one TPU: the kernel would
+    pad a copy of the arena to 128 lanes every layer, so the dispatch keeps
+    the streamed read, and the chat decode tick compiles for the v5e at its
+    widths with no kernel in it."""
+    from repro.configs.registry import get_config
+    from repro.models import attention
+    from repro.models.transformer import make_model
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    read = make_model(get_config("stablelm-1.6b")).paged_read_path_on(1)
+    assert read == "streamed"
+    text = _served_tick_text(one_chip, "stablelm-1.6b", 16, 1, 128, kv_dtype, read)
+    assert "tpu_custom_call" not in text
 
 
 def test_paged_layer_scopes_survive_the_tpu_compiler(one_chip):
